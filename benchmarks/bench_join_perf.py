@@ -139,13 +139,10 @@ def _time_build_program(ex, rels, repeats):
         dev = C.device_columns(rel)
         flat = tuple(v for lv in lo.levels for v in lv)
         used = {v: dev[v] for v in flat}
-        plans.append((used, lo, C.TRIE_CACHE._key_bits(rel, flat)))
+        plans.append((used, lo))
 
     def build_all():
-        return [
-            C._build_trie_jit(used, lo, ex.impl, ex.budget, kb, None, 0)
-            for used, lo, kb in plans
-        ]
+        return [C._build_trie_jit(used, lo, ex.impl, ex.budget) for used, lo in plans]
 
     t, _ = timeit(lambda: jax.block_until_ready(build_all()), repeats=repeats, warmup=1)
     return t
@@ -455,8 +452,9 @@ def run_distributed(
 ):
     """Compiled-distributed star-query rows (see module docstring, part 3).
     Each shard count runs in its own subprocess with that many fake CPU
-    devices; full runs append spmd_* fields to the BENCH_join_perf.json
-    record written by run_compiled_vs_eager."""
+    devices, pinned to the CPU platform so a child never reaches for an
+    accelerator this process holds; full runs append spmd_* fields to the
+    BENCH_join_perf.json record written by run_compiled_vs_eager."""
     import os
     import subprocess
     import sys as _sys
@@ -469,6 +467,7 @@ def run_distributed(
             **os.environ,
             "XLA_FLAGS": f"--xla_force_host_platform_device_count={shards} "
             + os.environ.get("XLA_FLAGS", ""),
+            "JAX_PLATFORMS": "cpu",
             "PYTHONPATH": "src" + os.pathsep + os.environ.get("PYTHONPATH", ""),
         }
         res = subprocess.run(

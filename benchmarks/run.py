@@ -14,6 +14,7 @@ import sys
 import time
 
 from benchmarks.common import emit
+from repro.compile_cache import enable_compile_cache
 
 SUITES = [
     "job",
@@ -46,6 +47,7 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma-separated subset of " + ",".join(SUITES))
     ap.add_argument("--smoke", action="store_true", help="CI scale: tiny inputs, one repeat")
     args = ap.parse_args()
+    enable_compile_cache()
     picks = args.only.split(",") if args.only else SUITES
     all_rows = []
     for name in picks:

@@ -48,7 +48,9 @@ class ExecOptions:
     template keys, and every planner/executor build, replacing the loose
     kwarg set compiled_free_join used to take.
 
-    impl: kernel implementation ("jnp" | "pallas_interpret" | "pallas");
+    impl: kernel implementation ("jnp" | "pallas_interpret" | "pallas";
+    "pallas" is refused up front on a TPU backend — its kernels do not
+    compile there yet);
     budget: hash-probe displacement budget; safety: multiplier on planner
     cardinality estimates; compact_threshold: schedule compaction when the
     live fraction is estimated to drop below this; jit: jax.jit the
@@ -75,6 +77,17 @@ class ExecOptions:
     chain_stages: bool = True
     optimize_level: int = 1
     verify: bool = False
+
+    def __post_init__(self):
+        if self.impl == "pallas":
+            import jax
+
+            if jax.default_backend() == "tpu":
+                raise ValueError(
+                    'ExecOptions(impl="pallas"): the Pallas kernels in repro.kernels '
+                    "have not yet been written to compile for a TPU (its compiler "
+                    'refuses their gathers); use impl="jnp"'
+                )
 
 
 # one release of backwards compatibility: compiled_free_join's old loose

@@ -315,7 +315,7 @@ def plan_capacities(
     this; max_capacity: clamp on planned (not grown) capacities.
     compact_output: allow a compact point on the final node too — for
     non-root stages of a chained bushy plan, whose output buffer feeds the
-    next stage's trie build (a squeezed buffer means a smaller lexsort),
+    next stage's trie build (a squeezed buffer means a smaller sort),
     there is always "more work" after the last probe.
     feedback: a relcache.CardFeedback — prefix estimates are replaced by
     measured cardinalities from prior runs where recorded (see

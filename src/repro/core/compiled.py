@@ -11,11 +11,16 @@ programs with an explicit contract between them:
   static length N (group counts are dynamic *values*, never dynamic
   *shapes*). COLT's "build only what the plan consumes" survives statically
   twice over: only levels the plan probes get hash tables, and a relation
-  that is only iterated at a single level skips the build entirely. The
-  sort itself is the segmented radix kernel (kernels/radix_sort.py):
-  level-by-level LSD passes inside the parent groups, with pass count set
-  by each var's key width — jnp.lexsort remains only as the fallback for
-  keys that may be negative (SPMD pad sentinels, weighted stage buffers).
+  that is only iterated at a single level skips the build entirely. Who
+  sorts depends on who dispatches the build. A build dispatched from the
+  host (every cached, rebuilt or merged trie, and a standing query's
+  stage tries) takes its row order and its tables' slot orders from
+  ops.lex_order, one XLA sort program per size bucket; its own programs
+  hold no sort (host_sorted_trie). A build traced into a larger program
+  (an executor's stage-output or raw-column trie, an SPMD shard) sorts
+  inline (jnp.lexsort), and on a TPU that program pays 20-60 s more
+  compile per sort at a few 100k rows and up, once per shape, kept by the
+  compile cache.
   A StaticTrie is a registered pytree, so a prebuilt trie crosses the jit
   boundary as a plain *input* of device arrays.
 
@@ -35,25 +40,24 @@ programs with an explicit contract between them:
   die with their relations, see core/relcache.py) + level layout + budget,
   revalidated per column by host-array identity, and lazy per level: a
   schedule that probes a level the cached build skipped adds exactly that
-  level's table; a level sequence prefix-compatible with a cached one
-  reuses the cached sort order and pays no sorting pass for the shared
-  prefix. Weighted (stage-output) tries are never cached: their rows are
-  padded frontier lanes of one specific run, so reuse across runs would
-  serve stale intermediates.
+  level's table; a layout whose level vars form the same sequence as a
+  cached one's (((x,), (y,)) and ((x, y),)) reuses the cached sort order
+  and pays no sort. Weighted (stage-output) tries are never cached: their
+  rows are padded frontier lanes of one specific run, so reuse across runs
+  would serve stale intermediates.
 
 * Since PR 9 the cache has a DELTA path for relations mutated through
   core/relcache.py's append/delete API, replacing rebuild-on-any-change.
   A mutating relation's trie is padded to a power-of-two capacity bucket
   (_bucket), pad rows carrying PAD_KEY keys and multiplicity 0 so they
   sort to the tail and weigh nothing. An append sorts ONLY the delta
-  (segmented radix kernel, the delta's own key width) and splices the
-  sorted run into the cached level buffers with a rank-merge
-  (_merge_append_jit): lex_searchsorted ranks each delta row against the
-  old sorted order, position arithmetic scatters both runs into the new
-  order, and the trie is rebuilt through the presorted constructor
-  bypass — zero sort passes over old rows. The real row count crosses
-  the jit boundary as a device scalar, so same-bucket appends reuse one
-  compiled merge program. A delete tombstones rows in place
+  (ops.lex_order, from the host) and splices the sorted run into the
+  cached level buffers with a rank-merge (_merge_append_jit):
+  lex_searchsorted ranks each delta row against the old sorted order,
+  position arithmetic scatters both runs into the new order, and the trie
+  is rebuilt over that order — zero sort passes over old rows. The real
+  row count crosses the jit boundary as a device scalar, so same-bucket
+  appends reuse one compiled merge program. A delete tombstones rows in place
   (_retire_rows_jit zeroes their weights and refreshes group weights);
   when live/total drops below the state's compact_ratio, relcache
   compacts and the next access pays one honest rebuild. Counters
@@ -110,7 +114,7 @@ import numpy as np
 
 from repro.core import faults, membudget, relcache
 from repro.core.plan import FreeJoinPlan
-from repro.kernels import ops
+from repro.kernels import ops, scan
 
 # Key stamped on the pad (invalid) lanes of a materialized stage buffer.
 # Real join keys are dictionary-encoded int32 >= 0 and never reach int32
@@ -172,13 +176,12 @@ class StaticTrie:
 
     Constructing one IS the build program; a built instance is a registered
     pytree of device arrays, so it can be returned from a jit'd build and
-    fed to a jit'd probe program as an ordinary input. `key_bits` (one
-    width per level var, in level order) routes the sort to the segmented
-    radix kernel; None, an empty relation, or a weighted build fall back to
-    jnp.lexsort (weighted/pad keys can be negative or PAD_KEY-wide).
-    `init_order`/`presorted` seed the sort with a cached permutation
-    already sorted by the first `presorted` level vars (TrieCache's
-    prefix-compatible order sharing).
+    fed to a jit'd probe program as an ordinary input. `order`, when given,
+    is the rows' lexicographic order over the level vars, already sorted by
+    the caller (ops.lex_order, a delta merge, a cached trie's order): the
+    build then pays no sort. Without it the build sorts in-graph
+    (jnp.lexsort).
+    tables=False leaves the probed levels' hash tables to the caller.
 
     `mult` (optional) marks a *weighted* trie built from another stage's
     padded output buffer: row i carries multiplicity mult[i] >= 0, and rows
@@ -196,9 +199,8 @@ class StaticTrie:
         impl: str,
         budget: int = 32,
         mult: jnp.ndarray | None = None,
-        key_bits: tuple[int, ...] | None = None,
-        init_order: jnp.ndarray | None = None,
-        presorted: int = 0,
+        order: jnp.ndarray | None = None,
+        tables: bool = True,
     ):
         self.impl = impl
         self.budget = budget
@@ -228,21 +230,9 @@ class StaticTrie:
         if self.trivial:  # pure cover: iterate the base table, zero build
             return
         all_vars = [v for lv in lops.levels for v in lv]
-        if init_order is not None and presorted >= len(all_vars) and not self.empty:
-            # delta-merge build (TrieCache._merge_append): the caller already
-            # holds the full lexicographic permutation — spliced from a cached
-            # sorted run and a sorted delta — so the build pays zero sorting
-            # passes, only the group-structure scans below
-            order = init_order
-        elif key_bits is not None and not self.empty and mult is None:
-            order = ops.segmented_sort(
-                [self.cols[v] for v in all_vars],
-                tuple(key_bits),
-                impl=impl,
-                init_order=init_order,
-                presorted=presorted,
-            )
-        else:
+        if self.empty:
+            order = jnp.zeros(1, jnp.int32)
+        elif order is None:
             order = jnp.lexsort(tuple(self.cols[v] for v in reversed(all_vars)))
         self.order = order.astype(jnp.int32)
         sc = {v: self.cols[v][order] for v in all_vars}
@@ -261,23 +251,29 @@ class StaticTrie:
                 diff = diff.at[1:].set(diff[1:] | (sc[v][1:] != sc[v][:-1]))
             flag = flag | diff
             flag = flag.at[0].set(True)
-            gd1 = (jnp.cumsum(flag.astype(jnp.int32)) - 1).astype(jnp.int32)  # g[d+1]
+            gd1 = (scan.cumsum(flag.astype(jnp.int32)) - 1).astype(jnp.int32)  # g[d+1]
+            # group ids are non-decreasing along the sorted rows, so every
+            # segment reduction below is a sorted scatter: the TPU compiler
+            # sorts the indices of an unsorted one, at a large compile cost
             # children of each depth-d group (counts over depth-(d+1) firsts)
-            ccnt = jax.ops.segment_sum(flag.astype(jnp.int32), self.g[d], num_segments=n)
-            cbase = jnp.cumsum(ccnt) - ccnt
-            kp = jnp.zeros(n + 1, jnp.int32).at[jnp.where(flag, gd1, n)].set(idx, mode="drop")
-            rcnt = jax.ops.segment_sum(jnp.ones(n, jnp.int32), gd1, num_segments=n)
+            ccnt = _sorted_segment_sum(flag.astype(jnp.int32), self.g[d], n)
+            cbase = scan.cumsum(ccnt) - ccnt
+            # first position of each group (0 past the last group)
+            kp = jnp.full(n, n, jnp.int32).at[gd1].min(idx, indices_are_sorted=True)
+            kp = jnp.where(kp < n, kp, 0)
+            rcnt = _sorted_segment_sum(jnp.ones(n, jnp.int32), gd1, n)
             self.g.append(gd1)
-            self.kpos.append(kp[:n])
+            self.kpos.append(kp)
             self.child_base.append(cbase.astype(jnp.int32))
             self.child_counts.append(ccnt.astype(jnp.int32))
             self.row_count.append(rcnt)
             if sm is not None:
-                self.row_weight.append(jax.ops.segment_sum(sm, gd1, num_segments=n))
+                self.row_weight.append(_sorted_segment_sum(sm, gd1, n))
             # probed levels get their hash table; one shared construction
             # with the lazy path (build_level_table), so eagerly- and
             # lazily-built tables can never drift
-            self.tables.append(self.build_level_table(d, budget) if lops.probed[d] else None)
+            probed = lops.probed[d] and tables
+            self.tables.append(self.build_level_table(d, budget) if probed else None)
 
     # -- pytree protocol: a built trie crosses jit boundaries as an input --
 
@@ -322,11 +318,11 @@ class StaticTrie:
         t.trivial = t.L == 1 and not t.lops.probed[0]
         return t
 
-    def build_level_table(self, d: int, budget: int | None = None):
+    def build_level_table(self, d: int, budget: int | None = None, *, host_sort=False):
         """Build the depth-d probe table on an already-sorted trie — the
         lazy-COLT path for a schedule that probes a level the cached build
         skipped. Device work is exactly one table build; the sort and the
-        group structure are reused."""
+        group structure are reused. host_sort: see ops.build_table."""
         assert not self.trivial and self.g is not None
         lv = self.levels[d]
         n = self.n
@@ -338,7 +334,7 @@ class StaticTrie:
         key_rows = jnp.stack(
             [parent] + [jnp.where(flag, self.sorted_cols[v], 0) for v in lv], axis=1
         )
-        return ops.build_table(key_rows, budget=budget or self.budget)
+        return ops.build_table(key_rows, budget=budget or self.budget, host_sort=host_sort)
 
     def table_view(self, probed: tuple[bool, ...]) -> "StaticTrie":
         """A shallow view sharing every array, exposing tables only where
@@ -426,6 +422,13 @@ jax.tree_util.register_pytree_node(
 )
 
 
+def _sorted_segment_sum(data, segment_ids, num_segments: int):
+    """segment_sum over non-decreasing `segment_ids` (a trie's group ids)."""
+    return jax.ops.segment_sum(
+        data, segment_ids, num_segments=num_segments, indices_are_sorted=True
+    )
+
+
 def build_trie(
     cols: dict[str, jnp.ndarray],
     lops: _LevelOps,
@@ -433,39 +436,49 @@ def build_trie(
     impl: str = "jnp",
     budget: int = 32,
     mult: jnp.ndarray | None = None,
-    key_bits: tuple[int, ...] | None = None,
-    init_order: jnp.ndarray | None = None,
-    presorted: int = 0,
+    order: jnp.ndarray | None = None,
+    tables: bool = True,
 ) -> StaticTrie:
     """The explicit build step: columns in, a StaticTrie pytree of device
     arrays out. Traceable — called inside the probe program for raw column
     dicts and weighted stage buffers, or under its own jit (see
-    _build_trie_jit) by the cross-call cache."""
-    return StaticTrie(
-        cols,
-        lops,
-        impl,
-        budget,
-        mult=mult,
-        key_bits=key_bits,
-        init_order=init_order,
-        presorted=presorted,
-    )
+    _build_trie_jit) by the cross-call cache. tables=False leaves the
+    probed levels' hash tables to the caller (see add_tables)."""
+    return StaticTrie(cols, lops, impl, budget, mult=mult, order=order, tables=tables)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("lops", "impl", "budget", "key_bits", "presorted")
-)
-def _build_trie_jit(cols, lops, impl, budget, key_bits, init_order, presorted):
+@functools.partial(jax.jit, static_argnames=("lops", "impl", "budget", "tables"))
+def _build_trie_jit(cols, lops, impl, budget, mult=None, order=None, tables=True):
     return build_trie(
-        cols,
-        lops,
-        impl=impl,
-        budget=budget,
-        key_bits=key_bits,
-        init_order=init_order,
-        presorted=presorted,
+        cols, lops, impl=impl, budget=budget, mult=mult, order=order, tables=tables
     )
+
+
+def host_sorted_trie(cols: dict, lops: _LevelOps, impl: str, budget: int, mult=None):
+    """A build dispatched from the host: a cached or rebuilt relation's
+    trie, or a standing query's weighted stage-output trie (`mult`; pads
+    carry PAD_KEY keys and mult 0, and the sort routes them to the tail,
+    where every later merge expects them). The row order comes from the
+    shared sort programs (ops.lex_order), _build_trie_jit adds the group
+    structure over it, and the probed levels' tables follow, sorted the same
+    way. No program of the build holds a sort of its own: on a TPU each
+    would cost 20-60 s of compile time."""
+    flat = [v for lv in lops.levels for v in lv]
+    trivial = len(lops.levels) == 1 and not lops.probed[0]
+    empty = cols[flat[0]].shape[0] == 0
+    order = None if trivial or empty else ops.lex_order([cols[v] for v in flat])
+    trie = _build_trie_jit(cols, lops, impl, budget, mult=mult, order=order, tables=False)
+    return add_tables(trie, lops.probed, budget)
+
+
+def add_tables(trie: StaticTrie, probed, budget: int) -> StaticTrie:
+    """Build (from the host) the hash table of every probed level that has
+    none; returns the trie."""
+    if not trie.trivial:
+        for d, p in enumerate(probed):
+            if p and trie.tables[d] is None:
+                trie.tables[d] = trie.build_level_table(d, budget, host_sort=True)
+    return trie
 
 
 def _bucket(n: int, block: int = 1024) -> int:
@@ -475,18 +488,9 @@ def _bucket(n: int, block: int = 1024) -> int:
     return max(block, 1 << max(0, n - 1).bit_length())
 
 
-@functools.partial(jax.jit, static_argnames=("lops", "impl", "budget"))
-def _build_weighted_jit(cols, mult, lops, impl, budget):
-    """Full rebuild of a mutating relation's padded+weighted trie (cold
-    build, post-compaction, or a pruned delta log). Pads carry PAD_KEY keys
-    and mult 0; the lexsort routes them to the tail, where every later
-    merge expects them."""
-    return build_trie(cols, lops, impl=impl, budget=budget, mult=mult)
-
-
 @functools.partial(
     jax.jit,
-    static_argnames=("lops", "impl", "budget", "cap", "delta_bits", "has_mult"),
+    static_argnames=("lops", "impl", "budget", "cap", "has_mult"),
 )
 def _merge_append_jit(
     old_cols,
@@ -495,20 +499,21 @@ def _merge_append_jit(
     old_order,
     n_real,
     delta_cols,
+    delta_order,
     *,
     lops,
     impl,
     budget,
     cap,
-    delta_bits,
     has_mult,
 ):
     """Splice a sorted delta run into a cached padded trie — the delta
-    build program. Sorts ONLY the delta (segmented radix kernel over the
-    delta's own key widths), binary-searches each delta tuple's slot in the
-    cached sorted run (ops.lex_searchsorted), and derives the merged
-    permutation arithmetically; the constructor's presorted bypass then
-    rebuilds the group structure with zero sorting passes.
+    build program. Takes the delta's own sort order (`delta_order`, sorted
+    on the host by ops.lex_order; None for a trivial trie), binary-searches
+    each delta tuple's slot in the cached sorted run
+    (ops.lex_searchsorted), and derives the merged permutation
+    arithmetically; the constructor then rebuilds the group structure with
+    zero sorting passes.
 
     Shape discipline: every input keeps its bucket capacity and `n_real`
     (the live+tombstone prefix length) is a DEVICE scalar, so a stream of
@@ -543,11 +548,8 @@ def _merge_append_jit(
     new_mult = jnp.where(idx < n_new, new_mult, 0)
     if len(lops.levels) == 1 and not lops.probed[0]:
         # trivial (cover-only) trie: no order to maintain, just new columns
-        return build_trie(new_cols, lops, impl=impl, budget=budget, mult=new_mult)
-    # sort the delta among itself, then locate each tuple's splice slot
-    delta_order = ops.segmented_sort(
-        [delta_cols[v].astype(jnp.int32) for v in flat], tuple(delta_bits), impl=impl
-    ).astype(jnp.int32)
+        return build_trie(new_cols, lops, impl=impl, budget=budget, mult=new_mult, tables=False)
+    # locate each tuple of the sorted delta's splice slot
     ds = {v: delta_cols[v].astype(jnp.int32)[delta_order] for v in flat}
     # rank in the cached sorted run; real keys < PAD_KEY, so ranks never
     # land inside the pad tail and the merged real prefix is exactly n_new
@@ -557,9 +559,15 @@ def _merge_append_jit(
     pos_old = k + jnp.searchsorted(rank, k, side="right").astype(jnp.int32)
     # delta rows take indices [n_real, n_new); old pads shift up by m
     adj = old_order + jnp.where(old_order >= n_real, m, 0).astype(jnp.int32)
+    # both position runs are strictly increasing: sorted scatters, which
+    # the TPU compiler takes without sorting their indices
     new_order = jnp.zeros(cap, jnp.int32)
-    new_order = new_order.at[pos_old].set(adj, mode="drop")
-    new_order = new_order.at[pos_delta].set(n_real + delta_order, mode="drop")
+    new_order = new_order.at[pos_old].set(
+        adj, mode="drop", indices_are_sorted=True, unique_indices=True
+    )
+    new_order = new_order.at[pos_delta].set(
+        n_real + delta_order, mode="drop", indices_are_sorted=True, unique_indices=True
+    )
     # pads are interchangeable: identity-map the tail so `new_order` stays a
     # permutation regardless of how many pads the scatters dropped
     new_order = jnp.where(idx >= n_new, idx, new_order)
@@ -569,8 +577,8 @@ def _merge_append_jit(
         impl=impl,
         budget=budget,
         mult=new_mult,
-        init_order=new_order,
-        presorted=len(flat),
+        order=new_order,
+        tables=False,  # built from the host by the caller (add_tables)
     )
 
 
@@ -580,12 +588,10 @@ def _retire_rows_jit(mult, order, groups, rows):
     refresh the per-level weight aggregates. The sort order, group
     structure, and hash tables are untouched — dead rows keep their slots
     and simply weigh nothing."""
-    mult = mult.at[rows].set(0)
+    mult = mult.at[rows].set(0, indices_are_sorted=True, unique_indices=True)  # np.unique'd
     total = jnp.sum(mult)
     sm = mult[order] if order is not None else mult
-    weights = [
-        jax.ops.segment_sum(sm, gd1, num_segments=mult.shape[0]) for gd1 in groups
-    ]
+    weights = [_sorted_segment_sum(sm, gd1, mult.shape[0]) for gd1 in groups]
     return mult, total, weights
 
 
@@ -616,9 +622,9 @@ class TrieCache:
     the weakref registry so it dies with the relation; revalidated per
     column by host-array identity, so a replaced column rebuilds. Lazy per
     level: a request probing a level the cached build skipped adds only
-    that level's table (build_level_table); a level-var sequence sharing a
-    prefix with a cached one seeds the sort with the cached order and skips
-    the shared passes. Weighted builds are refused — stage-output tries are
+    that level's table (build_level_table); a layout with the same level-var
+    sequence as a cached one builds over the cached order with no sort.
+    Weighted builds are refused — stage-output tries are
     one run's padded lanes and must never be served across runs.
 
     MUTATING relations (those with a relcache.MutationState, i.e. touched
@@ -633,7 +639,7 @@ class TrieCache:
       delta and splices it into the cached sorted run (_merge_append_jit,
       zero full re-sorts; `delta_merges` counts these), a delete refreshes
       the weight aggregates in place (`tombstone_refreshes`);
-    * log pruned / compaction crossed / negative delta keys — full padded
+    * log pruned / compaction crossed — full padded
       weighted rebuild (counted in `builds`, like any cold build).
 
     A trie built BEFORE the relation's first mutation is adopted as the
@@ -654,29 +660,6 @@ class TrieCache:
         self.order_shares = 0  # builds that reused a cached sort order
         self.delta_merges = 0  # appends absorbed by sorted-run splicing
         self.tombstone_refreshes = 0  # deletes absorbed by weight refresh
-
-    def _key_bits(self, rel, flat_vars) -> tuple[int, ...] | None:
-        """Static per-var key widths for the radix sort, from the host
-        columns (cached per column object). None when any key may be
-        negative — those builds stay on lexsort."""
-        def width_of(host):
-            def compute():
-                if len(host) == 0:
-                    return 1
-                if int(host.min()) < 0:
-                    return None
-                return max(1, int(host.max()).bit_length())
-
-            return compute
-
-        bits = []
-        for v in flat_vars:
-            host = rel.columns[v]
-            w = relcache.memo(self._reg, rel, "key_bits", v, host, width_of(host))
-            if w is None:
-                return None
-            bits.append(w)
-        return tuple(bits)
 
     def get(
         self,
@@ -708,30 +691,27 @@ class TrieCache:
             view = self._serve(entry["trie"], lops, budget, count_hit=True)
             self._govern(rel, ns, key)
             return view
-        # miss: build, seeding the sort with any prefix-compatible cached
-        # order over the same (identical) columns
-        key_bits = self._key_bits(rel, flat)
-        init_order, presorted = None, 0
-        if key_bits is not None and not trivial:
+        # miss: build over the host-sorted row order, or over the order of
+        # a cached trie whose level vars are the same sequence over the
+        # same (identical) columns, which needs no sort at all
+        donor_order = None
+        if not trivial:
             for (levels2, _i2, _b2, _t2), e2 in ns.items():
                 donor = e2["trie"]
                 if donor.order is None or e2.get("version") is not None:
                     continue  # padded mutating orders never seed plain builds
                 flat2 = tuple(v for lv in levels2 for v in lv)
-                share = 0
-                while (
-                    share < min(len(flat), len(flat2))
-                    and flat[share] == flat2[share]
-                    and e2["cols"][flat2[share]] is used[flat[share]]
-                ):
-                    share += 1
-                if share > presorted:
-                    init_order, presorted = donor.order, share
-        trie = _build_trie_jit(used, lops, impl, budget, key_bits, init_order, presorted)
+                if flat2 == flat and all(e2["cols"][v] is used[v] for v in flat):
+                    donor_order = donor.order
+                    break
+        if donor_order is None:
+            trie = host_sorted_trie(used, lops, impl, budget)
+        else:
+            trie = _build_trie_jit(used, lops, impl, budget, order=donor_order, tables=False)
+            trie = add_tables(trie, lops.probed, budget)
+            self.order_shares += 1
         ns[key] = {"trie": trie, "cols": used}
         self.builds += 1
-        if presorted:
-            self.order_shares += 1
         self._govern(rel, ns, key)
         return trie.table_view(lops.probed)
 
@@ -766,7 +746,7 @@ class TrieCache:
             if p and not trie.trivial and trie.tables[d] is None
         ]
         for d in missing:
-            trie.tables[d] = trie.build_level_table(d, budget)
+            trie.tables[d] = trie.build_level_table(d, budget, host_sort=True)
             self.table_builds += 1
         if count_hit and not missing:
             self.hits += 1
@@ -804,13 +784,9 @@ class TrieCache:
                 return view
             for _ver, kind, payload in deltas:
                 if kind == "append":
-                    merged = self._merge_append(
+                    trie = self._merge_append(
                         trie, entry["n_real"], payload, lops, impl, budget
                     )
-                    if merged is None:  # negative delta keys: lexsort only
-                        entry = None
-                        break
-                    trie = merged
                     entry["n_real"] += len(next(iter(payload.values())))
                     self.delta_merges += 1
                 else:
@@ -838,7 +814,7 @@ class TrieCache:
             mult = jax.device_put(np.ascontiguousarray(hm))
         else:
             mult = (jnp.arange(cap, dtype=jnp.int32) < st.total).astype(jnp.int32)
-        trie = _build_weighted_jit(used, mult, lops, impl, budget)
+        trie = host_sorted_trie(used, lops, impl, budget, mult=mult)
         ns[key] = {
             "trie": trie,
             "cols": dict(trie.cols),
@@ -851,47 +827,43 @@ class TrieCache:
         return view
 
     def _merge_append(self, trie, n_real, payload, lops, impl, budget):
-        """Host wrapper for one append log entry: delta key widths, bucket
-        growth, explicit device_put of the delta, and the probed-union lops
-        (a merge rebuilds every table the cached trie had accumulated, so
-        other schedules stay warm). Returns None when the delta has
-        negative keys — the radix delta sort cannot order those."""
+        """Host wrapper for one append log entry: bucket growth, explicit
+        device_put of the delta, its sort order (ops.lex_order), and the
+        probed-union lops (a merge rebuilds every table the cached trie had
+        accumulated, so other schedules stay warm)."""
         flat = tuple(v for lv in lops.levels for v in lv)
         m = len(next(iter(payload.values())))
-        bits = []
-        for v in flat:
-            col = payload[v]
-            if int(col.min()) < 0:
-                return None
-            bits.append(max(1, int(col.max()).bit_length()))
         cap = _bucket(n_real + m)
         delta_dev = {
             v: jax.device_put(np.ascontiguousarray(payload[v].astype(np.int32)))
             for v in flat
         }
+        delta_order = None
         if trie.trivial:
             mlops = lops
         else:
+            delta_order = ops.lex_order([delta_dev[v] for v in flat])
             mlops = replace(
                 lops,
                 probed=tuple(
                     (t is not None) or p for t, p in zip(trie.tables, lops.probed)
                 ),
             )
-        return _merge_append_jit(
+        merged = _merge_append_jit(
             {v: trie.cols[v] for v in flat},
             trie.mult_col,
             trie.sorted_cols,
             trie.order,
             jax.device_put(np.int32(n_real)),
             delta_dev,
+            delta_order,
             lops=mlops,
             impl=impl,
             budget=budget,
             cap=cap,
-            delta_bits=tuple(bits),
             has_mult=trie.mult_col is not None,
         )
+        return add_tables(merged, mlops.probed, budget)
 
     def _retire(self, trie, rows):
         """Apply one delete log entry to the cached trie in place: rows are

@@ -56,12 +56,6 @@ from repro.relational.npkit import mix64
 from repro.relational.relation import Relation
 from repro.relational.schema import Query
 
-try:  # top-level alias only exists on newer jax
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
-
-
 def _query_sig(query: Query) -> tuple:
     """Hashable structural identity of a query (its hyperedges in order)."""
     return tuple((a.alias, a.vars) for a in query.atoms)
@@ -230,14 +224,18 @@ def _mask_pad(cols: dict[str, dict[str, jnp.ndarray]], counts: dict[str, jnp.nda
 _partition_cache = relcache.KeyedCache(max_entries=8)
 
 
-def _cached_partition(query: Query, relations, shares, num_shards: int):
-    """Dense device fragments for (query, shares, num_shards), reused when
-    every relation object is identical to the cached entry's."""
+def _cached_partition(query: Query, relations, shares, mesh, axis: str):
+    """Dense device fragments for (query, shares, mesh), reused when every
+    relation object is identical to the cached entry's. Each shard's rows
+    are placed straight onto its own device (sharded along `axis`), so no
+    single device ever holds every fragment."""
+    num_shards = mesh.shape[axis]
     rels = [relations[a.alias] for a in query.atoms]
     key = (
         _query_sig(query),
         tuple(sorted(shares.items())),
-        num_shards,
+        mesh,
+        axis,
         tuple(id(r) for r in rels),
     )
     hit = _partition_cache.get(key)
@@ -245,8 +243,9 @@ def _cached_partition(query: Query, relations, shares, num_shards: int):
         return hit
     shards = partition(query, relations, shares, num_shards)
     dense, counts = pad_shards_to_dense(shards, query)
-    dense = jax.tree.map(jnp.asarray, dense)
-    counts = jax.tree.map(jnp.asarray, counts)
+    sharding = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(axis))
+    dense = jax.device_put(dense, sharding)
+    counts = jax.device_put(counts, sharding)
     _partition_cache.put(key, (dense, counts), rels)
     return dense, counts
 
@@ -297,17 +296,17 @@ def _cached_shard_tries(
         cols = jax.tree.map(lambda x: x[0], cols)
         cnts = jax.tree.map(lambda x: x[0], cnts)
         cols = _mask_pad(cols, cnts)
-        # lexsort path (key_bits=None): pad sentinels are negative
+        # sorted in-graph (jnp.lexsort): pad sentinels are negative
         tries = {a: StaticTrie(cols[a], level_ops[a], impl, budget) for a in level_ops}
         return jax.tree.map(lambda x: x[None], tries)
 
     built = jax.jit(
-        shard_map(
+        jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=pspec,
-            check_rep=False,
+            check_vma=False,
         )
     )(dense, counts)
     _shard_trie_cache.put(key, built, rels)
@@ -339,6 +338,36 @@ class _ShardStats:
 
     def distinct(self, alias: str, var: str) -> float:
         return max(1.0, self.base.distinct(alias, var) / self.shares.get(var, 1))
+
+
+def spmd_count_program(plan, capacities, schedule, mesh, axis: str, impl: str, tries):
+    """The jitted SPMD count for one capacity vector: on every device of
+    `mesh`, the local compiled executor over that shard's prebuilt tries,
+    then a psum of the counts and a pmax of the per-node needs. `tries`
+    (stacked along `axis`; arrays or ShapeDtypeStructs) fixes the input
+    structure."""
+    local = make_executor(plan, capacities, impl=impl, agg="count", schedule=schedule)
+    pspec, rspec = jax.sharding.PartitionSpec(axis), jax.sharding.PartitionSpec()
+
+    def per_shard(tries):
+        tries = jax.tree.map(lambda x: x[0], tries)
+        c, ne, nc = local(tries)
+        # count by psum; needs by pmax — the host retry loop sizes every
+        # device's next capacities to the worst shard's need
+        return jax.lax.psum(c, axis), jax.lax.pmax(ne, axis), jax.lax.pmax(nc, axis)
+
+    return jax.jit(
+        jax.shard_map(
+            per_shard,
+            mesh=mesh,
+            in_specs=(jax.tree.map(lambda _: pspec, tries),),
+            out_specs=(rspec, rspec, rspec),
+            # the probe's early-exit while_loop has no replication rule;
+            # outputs are explicitly psum/pmax-reduced above, so the check
+            # adds nothing here
+            check_vma=False,
+        )
+    )
 
 
 class SpmdCounter:
@@ -373,7 +402,7 @@ class SpmdCounter:
         sizes = {a.alias: relations[a.alias].num_rows for a in query.atoms}
         self.shares = hypercube_shares(query, sizes, num_shards)
         self._dense, self._counts = _cached_partition(
-            query, relations, self.shares, num_shards
+            query, relations, self.shares, mesh, axis
         )
         self._plan_key = None  # set only for planner-derived plans
         if cap_plan is not None:
@@ -439,8 +468,6 @@ class SpmdCounter:
             axis,
             impl,
         )
-        pspec = jax.sharding.PartitionSpec(axis)
-        self._in_specs = (jax.tree.map(lambda _: pspec, self._tries),)
         self._cache: dict[tuple, object] = {}
 
     @property
@@ -449,29 +476,9 @@ class SpmdCounter:
 
     def _fn(self, cp: CapacityPlan):
         if cp.capacities not in self._cache:
-            local = make_executor(
-                self.plan, cp.capacities, impl=self.impl, agg="count", schedule=self.schedule
-            )
-            axis, rspec = self.axis, jax.sharding.PartitionSpec()
-
-            def per_shard(tries):
-                tries = jax.tree.map(lambda x: x[0], tries)
-                c, ne, nc = local(tries)
-                # count by psum; needs by pmax — the host retry loop sizes
-                # every device's next capacities to the worst shard's need
-                return jax.lax.psum(c, axis), jax.lax.pmax(ne, axis), jax.lax.pmax(nc, axis)
-
-            self._cache[cp.capacities] = jax.jit(
-                shard_map(
-                    per_shard,
-                    mesh=self.mesh,
-                    in_specs=self._in_specs,
-                    out_specs=(rspec, rspec, rspec),
-                    # the probe's early-exit while_loop has no replication
-                    # rule; outputs are explicitly psum/pmax-reduced above,
-                    # so the check adds nothing here
-                    check_rep=False,
-                )
+            self._cache[cp.capacities] = spmd_count_program(
+                self.plan, cp.capacities, self.schedule, self.mesh, self.axis, self.impl,
+                self._tries,
             )
         return self._cache[cp.capacities]
 

@@ -30,11 +30,11 @@ Since PR 9 the registry also carries each relation's MUTATION STATE — the
 delta-build contract that replaced rebuild-on-any-change:
 
 * `append(rel, delta_cols)` extends the host columns AND primes every
-  identity-keyed memo (device upload, radix key width, distinct count)
+  identity-keyed memo (device upload, distinct count)
   with an incrementally-computed value, so the next planning/build pass
   pays O(delta), not O(N). The delta itself lands in a bounded version log
   that compiled.TrieCache replays: a cached trie catches up by sorting
-  only the delta (segmented radix kernel) and merging sorted runs — no
+  only the delta and merging sorted runs — no
   full re-sort.
 * `delete(rel, rows)` writes tombstones: rows keep their physical slots
   with multiplicity 0 (the weighted-trie mult-fold makes them contribute
@@ -396,7 +396,6 @@ def append(rel, delta_cols: dict) -> MutationState:
 
     * "dev_cols": the cached device upload is extended by a device-side
       concat of the delta — no O(N) host-to-device re-transfer;
-    * "key_bits": the radix sort width grows by a max over the delta;
     * "distinct": one np.union1d over the delta against the maintained
       sorted-distinct set (the optimizer's delta-aware size estimates).
 
@@ -417,7 +416,6 @@ def append(rel, delta_cols: dict) -> MutationState:
     if m == 0:
         return st
     dev_ns = REGISTRY.namespace(rel, "dev_cols")
-    bit_ns = REGISTRY.namespace(rel, "key_bits")
     dis_ns = REGISTRY.namespace(rel, "distinct")
     log_cols = {}
     for v in rel.schema:
@@ -427,13 +425,6 @@ def append(rel, delta_cols: dict) -> MutationState:
         hit = dev_ns.get(v)
         if hit is not None and hit[0] is old:
             dev_ns[v] = (new, jnp.concatenate([hit[1], jnp.asarray(delta, jnp.int32)]))
-        hit = bit_ns.get(v)
-        if hit is not None and hit[0] is old:
-            if hit[1] is None or int(delta.min()) < 0:
-                width = None
-            else:
-                width = max(hit[1], 1, int(delta.max()).bit_length())
-            bit_ns[v] = (new, width)
         uniq = st.uniques.get(v)
         if uniq is None:  # first append pays one full unique; then O(delta)
             uniq = np.unique(old)

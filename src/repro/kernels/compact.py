@@ -50,7 +50,7 @@ def compact_pallas(
     live: jnp.ndarray,
     *,
     capacity: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """csum: (N,) int32 inclusive prefix sum of the valid mask, N >= 1;
     live: (1,) int32 == csum[-1]. Returns src: (capacity,) int32 source lane

@@ -54,7 +54,7 @@ def csr_expand_pallas(
     total: jnp.ndarray,
     *,
     capacity: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """starts/base: (F,) int32, F >= 1; total: (1,) int32.
     Returns (fr, member): each (capacity,) int32, -1 beyond total."""

@@ -77,7 +77,7 @@ def hash_probe_pallas(
     table_keys: jnp.ndarray,
     query_keys: jnp.ndarray,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """slots: (cap + budget,) int32; table_keys: (N, K) int32 (N >= 1);
     query_keys: (Q, K) int32, Q % QBLK == 0. Returns (Q,) int32 row index

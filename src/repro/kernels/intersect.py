@@ -40,7 +40,7 @@ def _bsearch_kernel(b_ref, a_ref, mask_ref, pos_ref, *, n: int, steps: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def intersect_pallas(a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool = True):
+def intersect_pallas(a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool):
     """a: (Q,) int32 queries (Q % QBLK == 0); b: (N,) sorted int32, N >= 1.
     Returns (mask, pos): membership of each a[i] in b and its index."""
     n = int(b.shape[0])

@@ -9,9 +9,17 @@ Three implementations per op, selected by `impl`:
 
 The hash-table *build* is sort-based and stays in jnp by design: slot
 assignment after sorting by home slot is `slot_i = i + cummax(h_i - i)`
-(an associative scan), so XLA already emits the optimal sort + scan; there
-is no tiling decision for a kernel to make. The probe is where the kernel
-earns its keep (many probes per build, VPU-bound).
+(an associative scan); there is no tiling decision for a kernel to make.
+The probe is where the kernel earns its keep (many probes per build,
+VPU-bound).
+
+Every build sorts with XLA's sort, which runs fast on a TPU; but the TPU
+compiler takes 20-60 s for every program that holds a sort of a few 100k
+rows or more. A build dispatched from the host therefore sorts through
+`lex_order`: one standalone sort program per power-of-two size bucket,
+shared by every build and kept by the persistent compile cache. A build
+traced inside a larger program (an executor's stage-output trie, an SPMD
+shard) sorts inline, and that program pays the sort's compile once.
 """
 from __future__ import annotations
 
@@ -29,6 +37,7 @@ from repro.kernels.radix_sort import (  # noqa: F401  (impl trio inside)
     lex_searchsorted,
     segmented_sort,
 )
+from repro.kernels.scan import cummax, cumsum
 
 
 class Table(NamedTuple):
@@ -41,29 +50,88 @@ def _next_pow2(n: int) -> int:
     return max(8, 1 << (max(1, 2 * n) - 1).bit_length())
 
 
+_INT32_MAX = 2**31 - 1
+
+
+def _sort_bucket(n: int) -> int:
+    """Padded length of a `lex_order` sort: the next power of two, at
+    least 1024. The same rule on every backend; each bucket compiles once
+    per machine (the persistent compile cache keeps it)."""
+    return max(1024, 1 << max(0, n - 1).bit_length())
+
+
+@jax.jit
+def _lsd_pass(col, perm):
+    """One least-significant-key pass: reorder `perm` stably by col[perm].
+    The position as second key makes every key pair unique, so the
+    unstable two-operand sort (the cheapest to compile) returns the stable
+    order."""
+    pos = jnp.arange(perm.shape[0], dtype=jnp.int32)
+    return perm[jax.lax.sort((col[perm], pos), num_keys=2, is_stable=False)[1]]
+
+
+def lex_order(cols: list[jnp.ndarray]) -> jnp.ndarray:
+    """Stable lexicographic row order of int32 device columns (cols[0]
+    major): the order jnp.lexsort(cols[::-1]) returns. Call it from the
+    host, not inside a trace. It pads to `_sort_bucket` rows (pads sort
+    last) and runs one pass per column through `_lsd_pass`, the same
+    program for every column, table and relation of a size bucket."""
+    n = int(cols[0].shape[0])
+    b = _sort_bucket(n)
+    perm = jnp.arange(b, dtype=jnp.int32)
+    for c in reversed(cols):
+        c = c.astype(jnp.int32)
+        if b != n:
+            c = jnp.concatenate([c, jnp.full(b - n, _INT32_MAX, jnp.int32)])
+        perm = _lsd_pass(c, perm)
+    return perm[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("cap",))
+def _home_slots(keys: jnp.ndarray, cap: int) -> jnp.ndarray:
+    return mix32(keys) & (cap - 1)
+
+
 @functools.partial(jax.jit, static_argnames=("cap", "budget"))
-def _build(keys: jnp.ndarray, cap: int, budget: int = PROBE_BUDGET) -> Table:
+def _assign_slots(keys, h, order, cap: int, budget: int) -> Table:
+    """Linear-probing slots for rows taken in home-slot order."""
     n = keys.shape[0]
-    h = mix32(keys) & (cap - 1)
-    order = jnp.argsort(h).astype(jnp.int32)
     hs = h[order]
-    disp = jax.lax.cummax(hs - jnp.arange(n, dtype=jnp.int32))
+    disp = cummax(hs - jnp.arange(n, dtype=jnp.int32))
     slot = jnp.arange(n, dtype=jnp.int32) + disp
     max_disp = (slot - hs).max(initial=0)
     slots = jnp.full(cap + budget, -1, dtype=jnp.int32)
-    slots = slots.at[slot].set(order, mode="drop")
+    # slot is strictly increasing, so this is a sorted scatter (an unsorted
+    # one makes the TPU compiler sort its indices)
+    slots = slots.at[slot].set(order, mode="drop", indices_are_sorted=True, unique_indices=True)
     return Table(slots=slots, keys=keys, max_disp=max_disp)
 
 
-def build_table(keys: jnp.ndarray, budget: int = PROBE_BUDGET) -> Table:
+@functools.partial(jax.jit, static_argnames=("cap", "budget"))
+def _build(keys: jnp.ndarray, cap: int, budget: int) -> Table:
+    h = mix32(keys) & (cap - 1)
+    return _assign_slots(keys, h, jnp.argsort(h).astype(jnp.int32), cap, budget)
+
+
+def build_table(
+    keys: jnp.ndarray, budget: int = PROBE_BUDGET, *, host_sort: bool = False
+) -> Table:
     """keys: (N, K) int32, rows unique. Linear probing, load factor <= 0.5,
     no wraparound (tail margin = `budget`). max_disp >= budget would mean an
     overflow — astronomically unlikely at <=0.5 load; checked by callers in
     tests via table.max_disp. Smaller budgets shrink the unrolled probe loop
-    (§Perf J1) at the cost of a tighter displacement margin."""
+    (§Perf J1) at the cost of a tighter displacement margin.
+
+    The rows are sorted by home slot inline, as a caller tracing a larger
+    program needs; host_sort=True (a caller on the host, outside any trace)
+    sorts them with the shared `lex_order` programs instead."""
     if keys.ndim != 2:
         raise ValueError("keys must be (N, K)")
-    return _build(keys, _next_pow2(keys.shape[0]), budget)
+    cap = _next_pow2(keys.shape[0])
+    if not host_sort:
+        return _build(keys, cap, budget)
+    h = _home_slots(keys, cap)
+    return _assign_slots(keys, h, lex_order([h]), cap, budget)
 
 
 @functools.partial(jax.jit, static_argnames=("budget",))
@@ -146,7 +214,7 @@ def expand_counted(
     (fr, member, valid, total) with static `capacity`. Rows with count 0
     (e.g. invalid frontier slots) contribute nothing."""
     counts = counts.astype(jnp.int32)
-    cum = jnp.cumsum(counts)
+    cum = cumsum(counts)
     total = (cum[-1] if counts.shape[0] else jnp.int32(0)).astype(jnp.int32)
     starts = (cum - counts).astype(jnp.int32)
     base = base.astype(jnp.int32)
@@ -178,7 +246,7 @@ def compact_indices(
     n = valid.shape[0]
     if n == 0:
         return jnp.full(out_capacity, -1, jnp.int32), jnp.int32(0)
-    csum = jnp.cumsum(valid.astype(jnp.int32))
+    csum = cumsum(valid.astype(jnp.int32))
     live = csum[-1].astype(jnp.int32)
     if impl == "jnp":
         out = jnp.arange(out_capacity, dtype=jnp.int32)
@@ -203,7 +271,7 @@ def csr_expand_capped(
         z = jnp.full(capacity, -1, jnp.int32)
         return z, z, jnp.zeros(capacity, bool), jnp.int32(0)
     counts = (offsets[groups + 1] - offsets[groups]).astype(jnp.int32)
-    cum = jnp.cumsum(counts)
+    cum = cumsum(counts)
     total = cum[-1].astype(jnp.int32)
     starts = (cum - counts).astype(jnp.int32)
     base = offsets[groups].astype(jnp.int32)

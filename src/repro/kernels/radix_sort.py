@@ -32,16 +32,15 @@ one binary search per slot, the same VPU profile as csr_expand. The jnp
 variant keeps the scatter formulation (XLA fuses it); the Pallas kernel is
 the gather.
 
-Keys must be non-negative (join keys are dictionary-encoded int32 >= 0);
-negative sentinel keys (SPMD pad rows, PAD_KEY stage pads) stay on the
-lexsort path — see compiled.build_trie.
+Keys must be non-negative (join keys are dictionary-encoded int32 >= 0).
 
-On CPU the jnp variant runs within ~2x of XLA's comparison lexsort (the
-(N, R) histogram cumsums have no vector unit to feed); the design targets
-the TPU regime, where XLA's variadic sort is the known weak spot and every
-pass here is cumsum + per-row gather + one scatter — native VPU work. In
-the cached build-once architecture the sort runs once per relation either
-way, so cold-build cost is amortized to zero across calls.
+The engine's trie and table builds do not call this sort: they use XLA's
+sort (ops.lex_order from the host, jnp.lexsort/argsort inside traced
+programs). It stays for the kernel tests and benchmarks until the chip
+decides between them. On CPU the jnp variant runs within ~2x of XLA's
+comparison lexsort. On a TPU its unsorted scatter into more than ~2M slots
+makes the compiler sort the indices (about 20 s of compile per program at
+12M rows), and the Pallas kernel does not compile there yet.
 """
 from __future__ import annotations
 
@@ -80,7 +79,7 @@ def radix_rank_pallas(
     kd: jnp.ndarray,
     kt: jnp.ndarray,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """csum: (N, R) int32 inclusive per-digit prefix counts; kd/kt: (N,)
     int32 digit and target rank per output slot (N % SBLK == 0 is padded

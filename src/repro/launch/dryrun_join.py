@@ -27,12 +27,6 @@ from repro.launch.dryrun import _cost, _memory, collective_bytes  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.relational.schema import clover_query, triangle_query  # noqa: E402
 
-try:  # top-level alias only exists on newer jax
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map  # noqa: E402
-
-
 def lower_join(multi_pod: bool, rows_per_shard: int = 65536, cap: int = 1 << 20):
     mesh = make_production_mesh(multi_pod=multi_pod)
     axes = tuple(mesh.axis_names)  # flatten the whole grid into shards
@@ -61,14 +55,14 @@ def lower_join(multi_pod: bool, rows_per_shard: int = 65536, cap: int = 1 << 20)
         spec = P(axes)
         with mesh:
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     per_shard,
                     mesh=mesh,
                     in_specs=(jax.tree.map(lambda _: spec, cols_sds),),
                     out_specs=(P(), P()),
                     # the probe's early-exit while_loop has no replication
                     # rule; outputs are explicitly psum-reduced
-                    check_rep=False,
+                    check_vma=False,
                 )
             )
             t0 = time.time()

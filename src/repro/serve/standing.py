@@ -44,8 +44,8 @@ from repro.core.compiled import (
     PAD_KEY,
     TRIE_CACHE,
     AdaptiveExecutor,
-    _build_weighted_jit,
     device_columns,
+    host_sorted_trie,
     materialize_compiled,
 )
 from repro.core.optimizer import JoinOrderOptimizer, Stats
@@ -342,7 +342,7 @@ class StandingQueryEngine:
                     flat = [v for lv in lo.levels for v in lv]
                     cols = {v: jnp.where(valid, bound[v], PAD_KEY) for v in flat}
                     w = jnp.where(valid, mult, 0).astype(jnp.int32)
-                    trie = _build_weighted_jit(cols, w, lo, runner.impl, runner.budget)
+                    trie = host_sorted_trie(cols, lo, runner.impl, runner.budget, mult=w)
                     up.tries[key] = trie
                 data[a] = trie
                 continue

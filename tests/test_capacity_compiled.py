@@ -340,3 +340,19 @@ def test_optimize_bad_single_atom_returns_atom(rng):
     assert free_join(q, rels, tree, agg="count") == 25
     assert free_join(q, rels, optimize(q, rels), agg="count") == 25
     assert compiled_free_join(q, rels, agg="count") == 25
+
+
+# ---- kernel choice per backend ------------------------------------------
+
+
+def test_exec_options_refuse_pallas_on_tpu(monkeypatch):
+    """impl="pallas" fails at option construction on a TPU backend, with
+    the reason, instead of deep inside a trace (or silently running jnp)."""
+    from repro.core.api import ExecOptions
+
+    assert ExecOptions(impl="pallas").impl == "pallas"  # CPU: left to the caller
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="not yet been written to compile for a TPU"):
+        ExecOptions(impl="pallas")
+    assert ExecOptions(impl="jnp").impl == "jnp"
+    assert ExecOptions(impl="pallas_interpret").impl == "pallas_interpret"

@@ -244,8 +244,14 @@ print("SPMD_OK", got)
 def test_spmd_count_8_devices_subprocess():
     """shard_map + psum on 8 fake CPU devices (subprocess so the fake
     device count never leaks into this test session). Slow: compiles the
-    whole executor once per device mesh in a fresh process."""
-    env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8", "PYTHONPATH": "src"}
+    whole executor once per device mesh in a fresh process. The child is
+    pinned to the CPU: its shards are fake CPU devices by design, and an
+    accelerator this process holds would be out of its reach."""
+    env = {
+        "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+        "JAX_PLATFORMS": "cpu",
+        "PYTHONPATH": "src",
+    }
     import os
 
     env = {**os.environ, **env}

@@ -22,6 +22,33 @@ def test_hash_probe_vs_oracle(n, k, q, impl, rng):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("n", [1, 300, 5000])
+def test_build_table_host_sort_same_table(n, rng):
+    """Both home-slot sorts are stable, so the radix kernel and lex_order
+    lay out the same table."""
+    keys = np.unique(rng.integers(0, 10**6, (2 * n, 2)).astype(np.int32), axis=0)[:n]
+    keys = jnp.asarray(keys)
+    radix, host = ops.build_table(keys), ops.build_table(keys, host_sort=True)
+    np.testing.assert_array_equal(np.asarray(host.slots), np.asarray(radix.slots))
+    assert int(host.max_disp) == int(radix.max_disp)
+
+
+@pytest.mark.parametrize("n", [0, 1, 1024, 1025, 5000])
+def test_lex_order_is_stable_lexsort(n, rng):
+    """Signed int32 keys with many ties, int32 max among them (the pad
+    value of the size bucket): the order numpy's stable lexsort returns."""
+    lo, hi = np.iinfo(np.int32).min, np.iinfo(np.int32).max
+    cols = [rng.integers(-3, 4, n).astype(np.int32), rng.choice([lo, -1, 0, 7, hi], n).astype(np.int32)]
+    got = ops.lex_order([jnp.asarray(c) for c in cols])
+    np.testing.assert_array_equal(np.asarray(got), np.lexsort(cols[::-1]))
+
+
+def test_sort_bucket_is_next_power_of_two():
+    assert [ops._sort_bucket(n) for n in (0, 1, 1024, 1025, 5000, 12_000_000)] == [
+        1024, 1024, 1024, 2048, 8192, 1 << 24,
+    ]
+
+
 @pytest.mark.parametrize("m,n", [(1, 1), (100, 37), (1000, 999)])
 @pytest.mark.parametrize("impl", ["jnp", "pallas_interpret"])
 def test_intersect_vs_oracle(m, n, impl, rng):

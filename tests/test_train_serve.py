@@ -119,16 +119,12 @@ def test_data_stream_deterministic_and_elastic():
 COMPRESSION_SCRIPT = r"""
 import numpy as np, jax, jax.numpy as jnp
 from repro.train.compression import compressed_psum, init_error
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
 mesh = jax.make_mesh((4,), ("data",))
 g = {"w": jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.3}
 def f(gl, e):
     out, e2 = compressed_psum(gl, e, "data")
     return out, e2
-fn = jax.jit(shard_map(f, mesh=mesh,
+fn = jax.jit(jax.shard_map(f, mesh=mesh,
     in_specs=(jax.sharding.PartitionSpec("data"), jax.sharding.PartitionSpec("data")),
     out_specs=(jax.sharding.PartitionSpec("data"), jax.sharding.PartitionSpec("data"))))
 err = {"w": jnp.zeros((4, 8), jnp.float32)}
@@ -150,6 +146,7 @@ def test_compressed_psum_subprocess():
     env = {
         **os.environ,
         "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+        "JAX_PLATFORMS": "cpu",  # fake CPU devices by design, never an accelerator
         "PYTHONPATH": "src",
     }
     res = subprocess.run([sys.executable, "-c", COMPRESSION_SCRIPT], capture_output=True,
