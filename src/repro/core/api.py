@@ -27,7 +27,7 @@ from dataclasses import dataclass, fields, replace
 
 import numpy as np
 
-from repro.core import engine, faults, membudget, relcache
+from repro.core import engine, faults, membudget, obs, relcache
 from repro.core.plan import (
     BinaryPlan,
     FreeJoinPlan,
@@ -346,12 +346,13 @@ def _acquire_runner(
         # here (the new plan keys a new runner; the choice itself is
         # memoized against the feedback store's version, so steady state
         # pays one cache probe)
-        plan_tree = JoinOrderOptimizer(
-            level=options.optimize_level,
-            safety=options.safety,
-            compact_threshold=options.compact_threshold,
-            feedback=relcache.FEEDBACK,
-        ).choose(query, rels, stats=stats)
+        with obs.span("fj.plan"):
+            plan_tree = JoinOrderOptimizer(
+                level=options.optimize_level,
+                safety=options.safety,
+                compact_threshold=options.compact_threshold,
+                feedback=relcache.FEEDBACK,
+            ).choose(query, rels, stats=stats)
     stages = _stage_plans(query, plan_tree)
     # the hybrid path materializes fresh stage relations per call — a cache
     # entry keyed on them could never hit (and its put would evict a live
@@ -384,13 +385,14 @@ def _acquire_runner(
                 {a.alias: frozenset(v for v in a.vars if v in filter_vars)
                  for a in query.atoms},
             )
-        cap_plan = plan_chain_capacities(
-            stages,
-            stats=pstats,
-            safety=options.safety,
-            compact_threshold=options.compact_threshold,
-            feedback=relcache.FEEDBACK,
-        )
+        with obs.span("fj.plan"):
+            cap_plan = plan_chain_capacities(
+                stages,
+                stats=pstats,
+                safety=options.safety,
+                compact_threshold=options.compact_threshold,
+                feedback=relcache.FEEDBACK,
+            )
         if options.verify:
             # full pre-compile verification: plan structure, schedules,
             # capacities, stage DAG, filter coverage — findings raised as

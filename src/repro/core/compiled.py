@@ -112,7 +112,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import faults, membudget, relcache
+from repro.core import faults, membudget, obs, relcache
 from repro.core.plan import FreeJoinPlan
 from repro.kernels import ops, scan
 
@@ -522,64 +522,67 @@ def _merge_append_jit(
     real rows, so they stay a contiguous tail that the merge shifts and
     renormalizes with pure elementwise ops; scatters use mode="drop" for
     the pads pushed past the (possibly grown) capacity `cap`."""
-    flat = [v for lv in lops.levels for v in lv]
-    some = next(iter(delta_cols.values()))
-    m = some.shape[0]
-    c_old = next(iter(old_cols.values())).shape[0]
-    n_new = n_real + m  # dynamic value, static bound cap >= host n_real + m
-    idx = jnp.arange(cap, dtype=jnp.int32)
+    with jax.named_scope("merge"):
+        flat = [v for lv in lops.levels for v in lv]
+        some = next(iter(delta_cols.values()))
+        m = some.shape[0]
+        c_old = next(iter(old_cols.values())).shape[0]
+        n_new = n_real + m  # dynamic value, static bound cap >= host n_real + m
+        idx = jnp.arange(cap, dtype=jnp.int32)
 
-    def extend(a, fill):
-        if cap > c_old:
-            a = jnp.concatenate([a, jnp.full(cap - c_old, fill, jnp.int32)])
-        return a
+        def extend(a, fill):
+            if cap > c_old:
+                a = jnp.concatenate([a, jnp.full(cap - c_old, fill, jnp.int32)])
+            return a
 
-    new_cols = {}
-    for v in old_cols:
-        delta = delta_cols[v].astype(jnp.int32)
-        new_cols[v] = jax.lax.dynamic_update_slice(
-            extend(old_cols[v], PAD_KEY), delta, (n_real,)
+        new_cols = {}
+        for v in old_cols:
+            delta = delta_cols[v].astype(jnp.int32)
+            new_cols[v] = jax.lax.dynamic_update_slice(
+                extend(old_cols[v], PAD_KEY), delta, (n_real,)
+            )
+        om = old_mult if has_mult else jnp.ones(c_old, jnp.int32)
+        om = jnp.where(jnp.arange(c_old, dtype=jnp.int32) < n_real, om, 0)
+        new_mult = jax.lax.dynamic_update_slice(
+            extend(om, 0), jnp.ones(m, jnp.int32), (n_real,)
         )
-    om = old_mult if has_mult else jnp.ones(c_old, jnp.int32)
-    om = jnp.where(jnp.arange(c_old, dtype=jnp.int32) < n_real, om, 0)
-    new_mult = jax.lax.dynamic_update_slice(
-        extend(om, 0), jnp.ones(m, jnp.int32), (n_real,)
-    )
-    new_mult = jnp.where(idx < n_new, new_mult, 0)
-    if len(lops.levels) == 1 and not lops.probed[0]:
-        # trivial (cover-only) trie: no order to maintain, just new columns
-        return build_trie(new_cols, lops, impl=impl, budget=budget, mult=new_mult, tables=False)
-    # locate each tuple of the sorted delta's splice slot
-    ds = {v: delta_cols[v].astype(jnp.int32)[delta_order] for v in flat}
-    # rank in the cached sorted run; real keys < PAD_KEY, so ranks never
-    # land inside the pad tail and the merged real prefix is exactly n_new
-    rank = ops.lex_searchsorted([old_sorted[v] for v in flat], [ds[v] for v in flat])
-    pos_delta = rank + jnp.arange(m, dtype=jnp.int32)
-    k = jnp.arange(c_old, dtype=jnp.int32)
-    pos_old = k + jnp.searchsorted(rank, k, side="right").astype(jnp.int32)
-    # delta rows take indices [n_real, n_new); old pads shift up by m
-    adj = old_order + jnp.where(old_order >= n_real, m, 0).astype(jnp.int32)
-    # both position runs are strictly increasing: sorted scatters, which
-    # the TPU compiler takes without sorting their indices
-    new_order = jnp.zeros(cap, jnp.int32)
-    new_order = new_order.at[pos_old].set(
-        adj, mode="drop", indices_are_sorted=True, unique_indices=True
-    )
-    new_order = new_order.at[pos_delta].set(
-        n_real + delta_order, mode="drop", indices_are_sorted=True, unique_indices=True
-    )
-    # pads are interchangeable: identity-map the tail so `new_order` stays a
-    # permutation regardless of how many pads the scatters dropped
-    new_order = jnp.where(idx >= n_new, idx, new_order)
-    return build_trie(
-        new_cols,
-        lops,
-        impl=impl,
-        budget=budget,
-        mult=new_mult,
-        order=new_order,
-        tables=False,  # built from the host by the caller (add_tables)
-    )
+        new_mult = jnp.where(idx < n_new, new_mult, 0)
+        if len(lops.levels) == 1 and not lops.probed[0]:
+            # trivial (cover-only) trie: no order to maintain, just new columns
+            return build_trie(
+                new_cols, lops, impl=impl, budget=budget, mult=new_mult, tables=False
+            )
+        # locate each tuple of the sorted delta's splice slot
+        ds = {v: delta_cols[v].astype(jnp.int32)[delta_order] for v in flat}
+        # rank in the cached sorted run; real keys < PAD_KEY, so ranks never
+        # land inside the pad tail and the merged real prefix is exactly n_new
+        rank = ops.lex_searchsorted([old_sorted[v] for v in flat], [ds[v] for v in flat])
+        pos_delta = rank + jnp.arange(m, dtype=jnp.int32)
+        k = jnp.arange(c_old, dtype=jnp.int32)
+        pos_old = k + jnp.searchsorted(rank, k, side="right").astype(jnp.int32)
+        # delta rows take indices [n_real, n_new); old pads shift up by m
+        adj = old_order + jnp.where(old_order >= n_real, m, 0).astype(jnp.int32)
+        # both position runs are strictly increasing: sorted scatters, which
+        # the TPU compiler takes without sorting their indices
+        new_order = jnp.zeros(cap, jnp.int32)
+        new_order = new_order.at[pos_old].set(
+            adj, mode="drop", indices_are_sorted=True, unique_indices=True
+        )
+        new_order = new_order.at[pos_delta].set(
+            n_real + delta_order, mode="drop", indices_are_sorted=True, unique_indices=True
+        )
+        # pads are interchangeable: identity-map the tail so `new_order` stays a
+        # permutation regardless of how many pads the scatters dropped
+        new_order = jnp.where(idx >= n_new, idx, new_order)
+        return build_trie(
+            new_cols,
+            lops,
+            impl=impl,
+            budget=budget,
+            mult=new_mult,
+            order=new_order,
+            tables=False,  # built from the host by the caller (add_tables)
+        )
 
 
 @jax.jit
@@ -671,7 +674,24 @@ class TrieCache:
         budget: int = 32,
         mult=None,
     ) -> StaticTrie:
+        """The trie of `rel` under `lops`, in the span `fj.trie.get` whose
+        stat `outcome` says what serving it took: a full build, a merge of
+        the delta log, a lazy table, or a hit."""
         assert mult is None, "weighted (stage-output) tries are never cached"
+        with obs.span("fj.trie.get") as sp:
+            before = self._tally()
+            trie = self._get(rel, dev_cols, lops, impl, budget)
+            moved = (o for o, b, a in zip(self._OUTCOMES, before, self._tally()) if a != b)
+            sp.stats(outcome=next(moved, "hit"))
+        return trie
+
+    _OUTCOMES = ("build", "merge", "table")
+
+    def _tally(self) -> tuple[int, int, int]:
+        """Work done so far, in `_OUTCOMES` order."""
+        return self.builds, self.delta_merges + self.tombstone_refreshes, self.table_builds
+
+    def _get(self, rel, dev_cols, lops, impl, budget) -> StaticTrie:
         ns = self._reg.namespace(rel, "tries")
         flat = tuple(v for lv in lops.levels for v in lv)
         used = {v: dev_cols[v] for v in flat}
@@ -1004,110 +1024,129 @@ def make_executor(
 
         def squeeze(bound, gid, mult, valid, cap, c_compact, i):
             """Pack the valid lanes into a fresh c_compact-wide frontier."""
-            src, live = ops.compact_indices(valid, c_compact, impl=impl)
-            need_compact[i] = live
-            srcc = jnp.clip(src, 0, cap - 1)
-            bound = {v: a[srcc] for v, a in bound.items()}
-            gid = {a: arr[srcc] for a, arr in gid.items()}
-            mult = mult[srcc]
-            if fvalid[0] is not None:
-                fvalid[0] = fvalid[0][srcc]
-            valid = jnp.arange(c_compact, dtype=jnp.int32) < live
+            with jax.named_scope("compact"):
+                src, live = ops.compact_indices(valid, c_compact, impl=impl)
+                need_compact[i] = live
+                srcc = jnp.clip(src, 0, cap - 1)
+                bound = {v: a[srcc] for v, a in bound.items()}
+                gid = {a: arr[srcc] for a, arr in gid.items()}
+                mult = mult[srcc]
+                if fvalid[0] is not None:
+                    fvalid[0] = fvalid[0][srcc]
+                valid = jnp.arange(c_compact, dtype=jnp.int32) < live
             return bound, gid, mult, valid, c_compact
 
         for i, ((k, cover, probes), c_next, c_compact, cp_idx) in enumerate(
             zip(schedule, capacities, compact_to, compact_probe)
         ):
-            t = tries[cover.alias]
-            d = depth[cover.alias]
-            g = gid.get(cover.alias, jnp.zeros(cap, jnp.int32))
-            last = d == t.L - 1
-            # a filtered var can never take the factorized-count shortcut:
-            # its comparison against the constant needs the bound values
-            needed = _needed_later_static(plan, k, probes, agg) | set(filter_idx)
-            if agg == "count" and not (set(cover.vars) & needed) and last and not (
-                set(cover.vars) & set(bound)
-            ):
-                # factorized count (static decision)
-                mult = mult * jnp.where(valid, t.rows_under(d, g), 1).astype(jnp.int32)
-                gid.pop(cover.alias, None)
-                depth[cover.alias] = t.L
-            else:
-                base, counts = t.iter_counts(d, g, last)
-                counts = jnp.where(valid, counts, 0)
-                fr, member, vnew, total = ops.expand_counted(base, counts, c_next, impl=impl)
-                need_expand[i] = total
-                frc = jnp.clip(fr, 0, cap - 1)
-                memc = jnp.clip(member, 0, max(t.n - 1, 0))
-                bound = {v: a[frc] for v, a in bound.items()}
-                gid = {a: arr[frc] for a, arr in gid.items()}
-                mult = mult[frc]
-                if fvalid[0] is not None:
-                    fvalid[0] = fvalid[0][frc]
-                valid = vnew
-                cap = c_next
-                cols, new_g = t.bind_iter(d, memc, last)
-                for v, cvals in zip(cover.vars, cols):
-                    if v in bound:  # semijoin on re-bound vars
-                        valid = valid & (bound[v] == cvals)
-                    else:
-                        bound[v] = cvals
-                        if v in filter_idx:  # constant selection, applied
-                            # the moment the var is bound
-                            hit = cvals == filter_consts[filter_idx[v]]
-                            if filter_kill:  # dead lanes never reach a probe
-                                valid = valid & hit
-                            elif fvalid[0] is None:  # layout-neutral mask
-                                fvalid[0] = hit
-                            else:
-                                fvalid[0] = fvalid[0] & hit
-                depth[cover.alias] = d + 1
-                if new_g is None or depth[cover.alias] == t.L:
-                    # last-level iteration enumerates physical rows, so bag
-                    # multiplicity is already accounted for — except on a
-                    # weighted (stage-output) trie, whose per-row mult folds
-                    # in here and whose mult-0 pad rows die on the spot.
-                    rm = t.iter_mult(memc)
-                    if rm is not None:
-                        mult = mult * jnp.where(valid, rm, 1)
-                        valid = valid & (rm > 0)
+            # named scopes reach the device trace as each op's name: node i,
+            # then its stage (expand, probe, compact, count)
+            with jax.named_scope(f"node{i}"):
+                t = tries[cover.alias]
+                d = depth[cover.alias]
+                g = gid.get(cover.alias, jnp.zeros(cap, jnp.int32))
+                last = d == t.L - 1
+                # a filtered var can never take the factorized-count shortcut:
+                # its comparison against the constant needs the bound values
+                needed = _needed_later_static(plan, k, probes, agg) | set(filter_idx)
+                if agg == "count" and not (set(cover.vars) & needed) and last and not (
+                    set(cover.vars) & set(bound)
+                ):
+                    # factorized count (static decision)
+                    with jax.named_scope("count"):
+                        rows = t.rows_under(d, g)
+                        mult = mult * jnp.where(valid, rows, 1).astype(jnp.int32)
                     gid.pop(cover.alias, None)
+                    depth[cover.alias] = t.L
                 else:
-                    gid[cover.alias] = new_g
-            compacted = False
-            for j, sa in enumerate(probes):
-                tp = tries[sa.alias]
-                dp = depth[sa.alias]
-                gp = gid.get(sa.alias, jnp.zeros(cap, jnp.int32))
-                keys = [bound[v] for v in sa.vars]
-                child = tp.probe(dp, jnp.where(valid, gp, -1), keys)
-                valid = valid & (child >= 0)
-                childc = jnp.clip(child, 0, max(tp.n - 1, 0))
-                depth[sa.alias] = dp + 1
-                if depth[sa.alias] == tp.L:
-                    mult = mult * jnp.where(valid, tp.rows_under(tp.L, childc), 1).astype(jnp.int32)
-                    gid.pop(sa.alias, None)
-                else:
-                    gid[sa.alias] = childc
-                if c_compact is not None and not compacted and j + 1 >= cp_idx and c_compact < cap:
-                    # squeeze dead lanes out mid-node: the remaining probes
-                    # (and all later nodes) run at c_compact
+                    with jax.named_scope("expand"):
+                        base, counts = t.iter_counts(d, g, last)
+                        counts = jnp.where(valid, counts, 0)
+                        fr, member, vnew, total = ops.expand_counted(
+                            base, counts, c_next, impl=impl
+                        )
+                        need_expand[i] = total
+                        frc = jnp.clip(fr, 0, cap - 1)
+                        memc = jnp.clip(member, 0, max(t.n - 1, 0))
+                        bound = {v: a[frc] for v, a in bound.items()}
+                        gid = {a: arr[frc] for a, arr in gid.items()}
+                        mult = mult[frc]
+                        if fvalid[0] is not None:
+                            fvalid[0] = fvalid[0][frc]
+                        valid = vnew
+                        cap = c_next
+                        cols, new_g = t.bind_iter(d, memc, last)
+                        for v, cvals in zip(cover.vars, cols):
+                            if v in bound:  # semijoin on re-bound vars
+                                valid = valid & (bound[v] == cvals)
+                            else:
+                                bound[v] = cvals
+                                if v in filter_idx:  # constant selection, applied
+                                    # the moment the var is bound
+                                    hit = cvals == filter_consts[filter_idx[v]]
+                                    if filter_kill:  # dead lanes never reach a probe
+                                        valid = valid & hit
+                                    elif fvalid[0] is None:  # layout-neutral mask
+                                        fvalid[0] = hit
+                                    else:
+                                        fvalid[0] = fvalid[0] & hit
+                        depth[cover.alias] = d + 1
+                        if new_g is None or depth[cover.alias] == t.L:
+                            # last-level iteration enumerates physical rows, so bag
+                            # multiplicity is already accounted for — except on a
+                            # weighted (stage-output) trie, whose per-row mult folds
+                            # in here and whose mult-0 pad rows die on the spot.
+                            rm = t.iter_mult(memc)
+                            if rm is not None:
+                                mult = mult * jnp.where(valid, rm, 1)
+                                valid = valid & (rm > 0)
+                            gid.pop(cover.alias, None)
+                        else:
+                            gid[cover.alias] = new_g
+                compacted = False
+                for j, sa in enumerate(probes):
+                    with jax.named_scope("probe"):
+                        tp = tries[sa.alias]
+                        dp = depth[sa.alias]
+                        gp = gid.get(sa.alias, jnp.zeros(cap, jnp.int32))
+                        keys = [bound[v] for v in sa.vars]
+                        child = tp.probe(dp, jnp.where(valid, gp, -1), keys)
+                        valid = valid & (child >= 0)
+                        childc = jnp.clip(child, 0, max(tp.n - 1, 0))
+                        depth[sa.alias] = dp + 1
+                        if depth[sa.alias] == tp.L:
+                            rows = tp.rows_under(tp.L, childc)
+                            mult = mult * jnp.where(valid, rows, 1).astype(jnp.int32)
+                            gid.pop(sa.alias, None)
+                        else:
+                            gid[sa.alias] = childc
+                    if (
+                        c_compact is not None
+                        and not compacted
+                        and j + 1 >= cp_idx
+                        and c_compact < cap
+                    ):
+                        # squeeze dead lanes out mid-node: the remaining probes
+                        # (and all later nodes) run at c_compact
+                        bound, gid, mult, valid, cap = squeeze(
+                            bound, gid, mult, valid, cap, c_compact, i
+                        )
+                        compacted = True
+                if c_compact is not None and not compacted and c_compact < cap:
+                    # probe-less node (or unreached compact point): after-node
                     bound, gid, mult, valid, cap = squeeze(
                         bound, gid, mult, valid, cap, c_compact, i
                     )
-                    compacted = True
-            if c_compact is not None and not compacted and c_compact < cap:
-                # probe-less node (or unreached compact point): after-node
-                bound, gid, mult, valid, cap = squeeze(bound, gid, mult, valid, cap, c_compact, i)
         ne = jnp.stack(need_expand) if nsched else jnp.zeros(0, jnp.int32)
         nc = jnp.stack(need_compact) if nsched else jnp.zeros(0, jnp.int32)
-        if fvalid[0] is not None:  # mask-mode filters fold in only here
-            valid = valid & fvalid[0]
-        if agg == "count":
-            return jnp.sum(jnp.where(valid, mult, 0)), ne, nc
-        # lanes that went through a weighted trie's probe path can survive
-        # with mult 0 (pad groups weigh nothing); they are not output rows
-        valid = valid & (mult > 0)
+        with jax.named_scope("count"):
+            if fvalid[0] is not None:  # mask-mode filters fold in only here
+                valid = valid & fvalid[0]
+            if agg == "count":
+                return jnp.sum(jnp.where(valid, mult, 0)), ne, nc
+            # lanes that went through a weighted trie's probe path can survive
+            # with mult 0 (pad groups weigh nothing); they are not output rows
+            valid = valid & (mult > 0)
         return bound, valid, mult, ne, nc
 
     return run
@@ -1471,7 +1510,12 @@ class AdaptiveExecutor:
         rel_data values are prebuilt StaticTries and/or raw column dicts
         (see make_executor). filter_consts: (F,) int32 in filter_vars
         order — or (batch, F) for a batched runner, which returns (B,)
-        counts (agg="count") or per-lane (bound, valid, mult)."""
+        counts (agg="count") or per-lane (bound, valid, mult). The call
+        is the span `fj.executor.call`, with a sequence id as its stat."""
+        with obs.span("fj.executor.call", seq=obs.seq()):
+            return self._call(rel_data, filter_consts)
+
+    def _call(self, rel_data, filter_consts):
         from repro.core.capacity import _round_block  # deferred: no cycle
 
         if self.filter_vars:
@@ -1493,13 +1537,15 @@ class AdaptiveExecutor:
         tightened = False
         faults.fire("overflow", batch=self.batch, max_capacity=self.max_capacity)
         for _ in range(self.max_retries + 1):
-            fn = self._fn(chain)
-            faults.fire("dispatch")
-            out = fn(rel_data, filter_consts) if self.filter_vars else fn(rel_data)
+            with obs.span("fj.executor.dispatch"):
+                fn = self._fn(chain)
+                faults.fire("dispatch")
+                out = fn(rel_data, filter_consts) if self.filter_vars else fn(rel_data)
+                obs.count("executor.dispatches")
             # ONE explicit d2h for the control plane: the per-stage need
             # vectors drive host-side overflow/tighten decisions. Results
             # stay on device until the caller reads them.
-            needs_e, needs_c = jax.device_get((out[-2], out[-1]))
+            needs_e, needs_c = obs.read((out[-2], out[-1]), "fj.executor.sync")
             grown = chain
             for s, (cp, ne_l, nc_l) in enumerate(zip(chain.stages, needs_e, needs_c)):
                 ne, nc = self._reduced(ne_l), self._reduced(nc_l)
@@ -1510,13 +1556,14 @@ class AdaptiveExecutor:
                     self._check_quota(chain, s, int(i), int(ne[i]), np.asarray(ne_l))
                     grown = grown.grow_to(s, int(i), int(ne[i]))
             if grown is not chain:
-                if self._govern_token is not None:
-                    # growth must fit the device-memory budget: a shed here
-                    # raises MemoryBudgetError into the degradation ladder
-                    # instead of growing past what the device can hold
-                    membudget.GOVERNOR.account(
-                        self._govern_token, self.frontier_nbytes(grown)
-                    )
+                with obs.span("fj.executor.grow"):
+                    if self._govern_token is not None:
+                        # growth must fit the device-memory budget: a shed
+                        # here raises MemoryBudgetError into the degradation
+                        # ladder instead of growing past what the device holds
+                        membudget.GOVERNOR.account(
+                            self._govern_token, self.frontier_nbytes(grown)
+                        )
                 chain = grown
                 self.retries += 1
                 continue
@@ -1550,6 +1597,7 @@ class AdaptiveExecutor:
             # stash the measured per-node expansion needs: exact frontier
             # lane counts, the optimizer's measured-cardinality feedback
             self._last_needs = tuple(self._reduced(ne) for ne in needs_e)
+            obs.count("executor.lanes", int(sum(int(n.sum()) for n in self._last_needs)))
             result = out[:-2]
             return result[0] if self.agg == "count" else result
         raise RuntimeError(
@@ -1674,7 +1722,7 @@ class AdaptiveExecutor:
         if self.agg == "count":
             # explicit d2h: the count read-back is the warm path's only
             # result transfer (see the transfer-guard regression test)
-            host = jax.device_get(out)
+            host = obs.read(out, "fj.result.read")
             return np.asarray(host, np.int64) if self.batch else int(host)
         if self.batch:
             bound, valid, mult = out
@@ -1691,7 +1739,7 @@ def materialize_compiled(bound, valid, mult):
     """Strip padding lanes from an agg=None result: returns (cols, mult) as
     host numpy arrays over live rows only (the eager engine's contract —
     expand duplicate multiplicities with engine.materialize)."""
-    bound, valid, mult = jax.device_get((bound, valid, mult))
+    bound, valid, mult = obs.read((bound, valid, mult), "fj.result.read")
     v = np.asarray(valid)
     cols = {name: np.asarray(a)[v].astype(np.int64) for name, a in bound.items()}
     return cols, np.asarray(mult)[v].astype(np.int64)

@@ -402,6 +402,13 @@ def append(rel, delta_cols: dict) -> MutationState:
     The delta lands in the version log; compiled.TrieCache replays it by
     sorting only the delta and merging sorted runs into the cached level
     buffers (zero full re-sorts)."""
+    from repro.core import obs  # deferred: relcache stays importable sans jax
+
+    with obs.span("fj.relcache.append"):
+        return _append(rel, delta_cols)
+
+
+def _append(rel, delta_cols: dict) -> MutationState:
     import jax.numpy as jnp  # deferred: relcache stays importable sans jax
 
     st = _state_of(rel)

@@ -66,8 +66,9 @@ def _lsd_pass(col, perm):
     The position as second key makes every key pair unique, so the
     unstable two-operand sort (the cheapest to compile) returns the stable
     order."""
-    pos = jnp.arange(perm.shape[0], dtype=jnp.int32)
-    return perm[jax.lax.sort((col[perm], pos), num_keys=2, is_stable=False)[1]]
+    with jax.named_scope("sort"):
+        pos = jnp.arange(perm.shape[0], dtype=jnp.int32)
+        return perm[jax.lax.sort((col[perm], pos), num_keys=2, is_stable=False)[1]]
 
 
 def lex_order(cols: list[jnp.ndarray]) -> jnp.ndarray:
@@ -76,41 +77,49 @@ def lex_order(cols: list[jnp.ndarray]) -> jnp.ndarray:
     host, not inside a trace. It pads to `_sort_bucket` rows (pads sort
     last) and runs one pass per column through `_lsd_pass`, the same
     program for every column, table and relation of a size bucket."""
+    from repro.core import obs  # deferred: repro.core imports this module
+
     n = int(cols[0].shape[0])
     b = _sort_bucket(n)
-    perm = jnp.arange(b, dtype=jnp.int32)
-    for c in reversed(cols):
-        c = c.astype(jnp.int32)
-        if b != n:
-            c = jnp.concatenate([c, jnp.full(b - n, _INT32_MAX, jnp.int32)])
-        perm = _lsd_pass(c, perm)
-    return perm[:n]
+    with obs.span("fj.trie.lex_order"):
+        perm = jnp.arange(b, dtype=jnp.int32)
+        for c in reversed(cols):
+            c = c.astype(jnp.int32)
+            if b != n:
+                c = jnp.concatenate([c, jnp.full(b - n, _INT32_MAX, jnp.int32)])
+            perm = _lsd_pass(c, perm)
+        return perm[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("cap",))
 def _home_slots(keys: jnp.ndarray, cap: int) -> jnp.ndarray:
-    return mix32(keys) & (cap - 1)
+    with jax.named_scope("table"):
+        return mix32(keys) & (cap - 1)
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "budget"))
 def _assign_slots(keys, h, order, cap: int, budget: int) -> Table:
     """Linear-probing slots for rows taken in home-slot order."""
-    n = keys.shape[0]
-    hs = h[order]
-    disp = cummax(hs - jnp.arange(n, dtype=jnp.int32))
-    slot = jnp.arange(n, dtype=jnp.int32) + disp
-    max_disp = (slot - hs).max(initial=0)
-    slots = jnp.full(cap + budget, -1, dtype=jnp.int32)
-    # slot is strictly increasing, so this is a sorted scatter (an unsorted
-    # one makes the TPU compiler sort its indices)
-    slots = slots.at[slot].set(order, mode="drop", indices_are_sorted=True, unique_indices=True)
+    with jax.named_scope("table"):
+        n = keys.shape[0]
+        hs = h[order]
+        disp = cummax(hs - jnp.arange(n, dtype=jnp.int32))
+        slot = jnp.arange(n, dtype=jnp.int32) + disp
+        max_disp = (slot - hs).max(initial=0)
+        slots = jnp.full(cap + budget, -1, dtype=jnp.int32)
+        # slot is strictly increasing, so this is a sorted scatter (an
+        # unsorted one makes the TPU compiler sort its indices)
+        slots = slots.at[slot].set(
+            order, mode="drop", indices_are_sorted=True, unique_indices=True
+        )
     return Table(slots=slots, keys=keys, max_disp=max_disp)
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "budget"))
 def _build(keys: jnp.ndarray, cap: int, budget: int) -> Table:
-    h = mix32(keys) & (cap - 1)
-    return _assign_slots(keys, h, jnp.argsort(h).astype(jnp.int32), cap, budget)
+    with jax.named_scope("table"):
+        h = mix32(keys) & (cap - 1)
+        return _assign_slots(keys, h, jnp.argsort(h).astype(jnp.int32), cap, budget)
 
 
 def build_table(

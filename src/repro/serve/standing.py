@@ -33,11 +33,10 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import faults, relcache
+from repro.core import faults, obs, relcache
 from repro.core.api import ExecOptions, _stage_plans, free_join
 from repro.core.capacity import CapacityQuotaError, plan_chain_capacities
 from repro.core.compiled import (
@@ -125,9 +124,9 @@ class StandingQueryEngine:
 
     `ingest(rel, delta_cols)` is the streaming front door: one
     relcache.append (delta trie merge downstream) followed by a refresh of
-    every registered query. Counters: `stage_runs` (stage executions),
-    `stages_skipped` (fingerprint hits that replayed cached buffers),
-    `stages_recomputed` (fingerprint misses)."""
+    every registered query. Counters: `stages_skipped` (fingerprint hits
+    that replayed cached buffers), `stages_recomputed` (fingerprint
+    misses)."""
 
     def __init__(
         self,
@@ -141,7 +140,6 @@ class StandingQueryEngine:
         # template key -> tuple of (name, plan, AdaptiveExecutor, stage filter
         # vars with their index into the template's consts vector)
         self._runners: dict = {}
-        self.stage_runs = 0
         self.stages_skipped = 0
         self.stages_recomputed = 0
         # refreshes that fell back to the eager host engine after a
@@ -187,23 +185,24 @@ class StandingQueryEngine:
             return runners
         o = template.options
         rels = dict(template.relations)
-        stats = Stats(rels, cached=True)
-        tree = template.plan_tree
-        if tree is None:
-            tree = JoinOrderOptimizer(
-                level=o.optimize_level,
+        with obs.span("fj.plan"):
+            stats = Stats(rels, cached=True)
+            tree = template.plan_tree
+            if tree is None:
+                tree = JoinOrderOptimizer(
+                    level=o.optimize_level,
+                    safety=o.safety,
+                    compact_threshold=o.compact_threshold,
+                    feedback=relcache.FEEDBACK,
+                ).choose(template.query, rels, stats=stats)
+            stages = _stage_plans(template.query, tree)
+            chain = plan_chain_capacities(
+                stages,
+                stats=stats,
                 safety=o.safety,
                 compact_threshold=o.compact_threshold,
                 feedback=relcache.FEEDBACK,
-            ).choose(template.query, rels, stats=stats)
-        stages = _stage_plans(template.query, tree)
-        chain = plan_chain_capacities(
-            stages,
-            stats=stats,
-            safety=o.safety,
-            compact_threshold=o.compact_threshold,
-            feedback=relcache.FEEDBACK,
-        )
+            )
         # first-binder filter assignment, mirroring make_chain_executor: a
         # var's selection runs in the first stage that binds it, and dead
         # rows carry mult 0 into every downstream weighted trie
@@ -250,6 +249,19 @@ class StandingQueryEngine:
         return changed
 
     def _refresh_query(self, sq: StandingQuery, runners) -> bool:
+        """One refresh of `sq`, in the span `fj.standing.refresh`, whose
+        stats count the stages it skipped and recomputed."""
+        skipped, recomputed = self.stages_skipped, self.stages_recomputed
+        with obs.span("fj.standing.refresh", seq=obs.seq()) as sp:
+            try:
+                return self._refresh_stages(sq, runners)
+            finally:
+                sp.stats(
+                    skipped=self.stages_skipped - skipped,
+                    recomputed=self.stages_recomputed - recomputed,
+                )
+
+    def _refresh_stages(self, sq: StandingQuery, runners) -> bool:
         rels = sq.template.relations
         states_by_name = sq.states_by_name
         root_changed = False
@@ -258,7 +270,6 @@ class StandingQueryEngine:
             stage_names = set(sq._stage_names[:i])
             fp = self._stage_fp(plan, stage_names, rels, states_by_name)
             is_root = i == len(runners) - 1
-            self.stage_runs += 1
             if _fp_equal(fp, state.fingerprint) and (is_root or state.out is not None):
                 self.stages_skipped += 1
                 continue
@@ -276,7 +287,7 @@ class StandingQueryEngine:
                 return True
             if is_root:
                 if sq.template.agg == "count":
-                    sq.result = int(jax.device_get(out))
+                    sq.result = int(obs.read(out, "fj.result.read"))
                 else:
                     sq.result = materialize_compiled(*out)
                 sq.result_version += 1
@@ -331,27 +342,28 @@ class StandingQueryEngine:
         raw), upstream stage aliases as weighted tries built once per
         upstream run from the cached output buffers."""
         data = {}
-        for a in {sa.alias for node in plan.nodes for sa in node}:
-            if a in stage_names:
-                up = states_by_name[a]
-                lo = runner.schedule.level_ops[a]
-                key = (lo.levels, lo.probed)
-                trie = up.tries.get(key)
-                if trie is None:
-                    bound, valid, mult = up.out
-                    flat = [v for lv in lo.levels for v in lv]
-                    cols = {v: jnp.where(valid, bound[v], PAD_KEY) for v in flat}
-                    w = jnp.where(valid, mult, 0).astype(jnp.int32)
-                    trie = host_sorted_trie(cols, lo, runner.impl, runner.budget, mult=w)
-                    up.tries[key] = trie
-                data[a] = trie
-                continue
-            rel = rels[a]
-            lo = runner._alias_lops.get(a)
-            if lo is not None:
-                data[a] = TRIE_CACHE.get(
-                    rel, device_columns(rel), lo, impl=runner.impl, budget=runner.budget
-                )
-            else:
-                data[a] = device_columns(relcache.live_relation(rel))
-        return data
+        with obs.span("fj.standing.stage_data"):
+            for a in {sa.alias for node in plan.nodes for sa in node}:
+                if a in stage_names:
+                    up = states_by_name[a]
+                    lo = runner.schedule.level_ops[a]
+                    key = (lo.levels, lo.probed)
+                    trie = up.tries.get(key)
+                    if trie is None:
+                        bound, valid, mult = up.out
+                        flat = [v for lv in lo.levels for v in lv]
+                        cols = {v: jnp.where(valid, bound[v], PAD_KEY) for v in flat}
+                        w = jnp.where(valid, mult, 0).astype(jnp.int32)
+                        trie = host_sorted_trie(cols, lo, runner.impl, runner.budget, mult=w)
+                        up.tries[key] = trie
+                    data[a] = trie
+                    continue
+                rel = rels[a]
+                lo = runner._alias_lops.get(a)
+                if lo is not None:
+                    data[a] = TRIE_CACHE.get(
+                        rel, device_columns(rel), lo, impl=runner.impl, budget=runner.budget
+                    )
+                else:
+                    data[a] = device_columns(relcache.live_relation(rel))
+            return data
