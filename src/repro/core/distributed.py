@@ -362,9 +362,9 @@ def spmd_count_program(plan, capacities, schedule, mesh, axis: str, impl: str, t
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: pspec, tries),),
             out_specs=(rspec, rspec, rspec),
-            # the probe's early-exit while_loop has no replication rule;
-            # outputs are explicitly psum/pmax-reduced above, so the check
-            # adds nothing here
+            # the probe's while_loop, bounded by each shard's own table, has
+            # no replication rule; outputs are explicitly psum/pmax-reduced
+            # above, so the check adds nothing here
             check_vma=False,
         )
     )

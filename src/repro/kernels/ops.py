@@ -43,7 +43,7 @@ from repro.kernels.scan import cummax, cumsum
 class Table(NamedTuple):
     slots: jnp.ndarray  # (cap + PROBE_BUDGET,) int32 row index or -1
     keys: jnp.ndarray  # (N, K) int32 key rows
-    max_disp: jnp.ndarray  # () int32: max probe distance used at build
+    max_disp: jnp.ndarray  # () int32: max distance of a key from its home slot
 
 
 def _next_pow2(n: int) -> int:
@@ -126,10 +126,12 @@ def build_table(
     keys: jnp.ndarray, budget: int = PROBE_BUDGET, *, host_sort: bool = False
 ) -> Table:
     """keys: (N, K) int32, rows unique. Linear probing, load factor <= 0.5,
-    no wraparound (tail margin = `budget`). max_disp >= budget would mean an
-    overflow — astronomically unlikely at <=0.5 load; checked by callers in
-    tests via table.max_disp. Smaller budgets shrink the unrolled probe loop
-    (§Perf J1) at the cost of a tighter displacement margin.
+    no wraparound (tail margin = `budget`). `max_disp`, the largest distance
+    of a key from its home slot, bounds the jnp probe: it reads
+    min(max_disp + 1, budget) slots per query. max_disp >= budget would mean
+    an overflow — astronomically unlikely at <=0.5 load; checked by callers
+    in tests via table.max_disp. Smaller budgets shrink the Pallas probe's
+    static loop (§Perf J1) at the cost of a tighter displacement margin.
 
     The rows are sorted by home slot inline, as a caller tracing a larger
     program needs; host_sort=True (a caller on the host, outside any trace)
@@ -144,37 +146,34 @@ def build_table(
 
 
 @functools.partial(jax.jit, static_argnames=("budget",))
-def _probe_jnp(slots, keys, queries, budget: int):
+def _probe_jnp(slots, keys, queries, max_disp, budget: int):
     # rolled as a while_loop, not a Python loop: XLA's CPU pipeline hits
     # multi-minute compiles on the 32x-unrolled gather chain at some small
     # shapes (run the tier-1 suite at 17 keys / 64 queries to reproduce);
-    # the rolled loop compiles in milliseconds. Early exit: at load factor
-    # <= 0.5 almost every lane resolves within the first couple of probe
-    # rounds, so the loop stops as soon as *all* lanes are done instead of
-    # always paying `budget` gather rounds — same results, identical math.
+    # the rolled loop compiles in milliseconds. Trip count: the build put
+    # every key at most `max_disp` slots past its home slot, so slots
+    # h .. h + max_disp hold the answer for hits and misses alike, and the
+    # loop reads exactly min(max_disp + 1, budget) of them. Rows are unique,
+    # so at most one of those slots matches. `budget` stays the hard cap: an
+    # overflowing table (max_disp >= budget) reads the whole margin.
     cap = slots.shape[0] - budget
     h = mix32(queries) & (cap - 1)
     nkeys = keys.shape[0]
+    last = jnp.minimum(max_disp, budget - 1)
 
     def cond(state):
-        p, _res, done = state
-        return (p < budget) & ~done.all()
+        p, _res = state
+        return p <= last
 
     def body(state):
-        p, res, done = state
+        p, res = state
         cand = slots[h + p]
-        is_empty = cand < 0
         krow = keys[jnp.clip(cand, 0, nkeys - 1)]
-        match = (~is_empty) & (krow == queries).all(axis=-1)
-        hit = match & ~done
-        return p + 1, jnp.where(hit, cand, res), done | hit | is_empty
+        match = (cand >= 0) & (krow == queries).all(axis=-1)
+        return p + 1, jnp.where(match, cand, res)
 
-    init = (
-        jnp.int32(0),
-        jnp.full(h.shape, -1, dtype=jnp.int32),
-        jnp.zeros(h.shape, dtype=bool),
-    )
-    _, res, _ = jax.lax.while_loop(cond, body, init)
+    init = (jnp.int32(0), jnp.full(h.shape, -1, dtype=jnp.int32))
+    _, res = jax.lax.while_loop(cond, body, init)
     return res
 
 
@@ -193,7 +192,7 @@ def probe(table: Table, queries: jnp.ndarray, impl: str = "jnp") -> jnp.ndarray:
         return jnp.full(queries.shape[0], -1, dtype=jnp.int32)
     if impl == "jnp":
         budget = table.slots.shape[0] - _next_pow2(table.keys.shape[0])
-        return _probe_jnp(table.slots, table.keys, queries, budget)
+        return _probe_jnp(table.slots, table.keys, queries, table.max_disp, budget)
     q, n = _pad_rows(queries, QBLK, 0)
     out = hash_probe_pallas(table.slots, table.keys, q, interpret=impl == "pallas_interpret")
     return out[:n]

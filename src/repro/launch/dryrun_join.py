@@ -60,8 +60,9 @@ def lower_join(multi_pod: bool, rows_per_shard: int = 65536, cap: int = 1 << 20)
                     mesh=mesh,
                     in_specs=(jax.tree.map(lambda _: spec, cols_sds),),
                     out_specs=(P(), P()),
-                    # the probe's early-exit while_loop has no replication
-                    # rule; outputs are explicitly psum-reduced
+                    # the probe's while_loop, bounded by each shard's own
+                    # table, has no replication rule; outputs are
+                    # explicitly psum-reduced
                     check_vma=False,
                 )
             )

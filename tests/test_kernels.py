@@ -22,6 +22,88 @@ def test_hash_probe_vs_oracle(n, k, q, impl, rng):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def _home(keys: np.ndarray, cap: int) -> np.ndarray:
+    return np.asarray(ops.mix32(jnp.asarray(keys)) & (cap - 1))
+
+
+def _long_run_layout(k: int):
+    """One key at each home slot of a 48-slot run, one more at its first
+    slot: max_disp 1, but a miss homed at the run's start meets no empty
+    slot within the 32-slot budget. Queries: every key, and misses homed
+    at the run's first and middle slots."""
+    cap, start, run = 128, 20, 48  # cap = ops._next_pow2(run + 1)
+    cand = np.stack([np.arange(1, 20_000, dtype=np.int32)] * k, axis=1)
+    cand[:, 1:] = 7
+    home = _home(cand, cap)
+    picks = [np.flatnonzero(home == s)[0] for s in range(start, start + run)]
+    picks.append(np.flatnonzero(home == start)[1])
+    misses = np.concatenate(
+        [np.flatnonzero(home == start)[2:12], np.flatnonzero(home == start + run // 2)[1:11]]
+    )
+    return cand[picks], np.vstack([cand[picks], cand[misses]])
+
+
+def _padded_trie_level_layout(rng):
+    """A trie's level-1 table keyed by (parent gid, c) over 4096 rows, pad
+    rows included (load 0.5): the first row of each group holds its key, any
+    other row i the unique dead key (-i-2, 0). Queries: every live key, a
+    miss under each parent, and one dead-lane key repeated."""
+    n, n_real = 4096, 3000
+    a = np.concatenate([rng.integers(0, 300, n_real), np.full(n - n_real, -1)]).astype(np.int32)
+    c = np.concatenate([rng.integers(0, 50, n_real), np.full(n - n_real, -1)]).astype(np.int32)
+    order = np.lexsort((c, a))
+    a, c = a[order], c[order]
+    first_a = np.r_[True, a[1:] != a[:-1]]
+    first_ac = first_a | np.r_[True, c[1:] != c[:-1]]
+    gid = np.cumsum(first_a).astype(np.int32) - 1
+    idx = np.arange(n, dtype=np.int32)
+    keys = np.stack([np.where(first_ac, gid, -idx - 2), np.where(first_ac, c, 0)], axis=1)
+    live = keys[first_ac]
+    parents = np.unique(gid)
+    miss = np.stack([parents, np.full_like(parents, 99)], axis=1)
+    dead = np.tile(np.array([[-1, 0]], np.int32), (2000, 1))
+    return keys.astype(np.int32), np.vstack([live, miss, dead]).astype(np.int32)
+
+
+@pytest.mark.parametrize("layout", ["long_run_k1", "long_run_k2", "padded_trie_level"])
+def test_hash_probe_jnp_table_layouts_vs_oracle(layout, rng):
+    if layout == "padded_trie_level":
+        keys, qs = _padded_trie_level_layout(rng)
+        assert ops._next_pow2(len(keys)) == 2 * len(keys)  # load 0.5
+    else:
+        keys, qs = _long_run_layout(int(layout[-1]))
+    table = ops.build_table(jnp.asarray(keys))
+    if layout != "padded_trie_level":
+        assert int(table.max_disp) == 1
+        assert int((np.asarray(table.slots) >= 0).sum()) == len(keys) > 32
+    want = ref.hash_probe_ref(jnp.asarray(keys), jnp.asarray(qs))
+    got = ops.probe(table, jnp.asarray(qs), impl="jnp")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("bound", [0, 1, 2, None])
+def test_probe_reads_the_tables_max_disp(bound, rng):
+    """The jnp probe reads slots h .. h + table.max_disp and no further:
+    with the bound forced lower, exactly the keys displaced past it are
+    lost; with the table's own bound, every key is found."""
+    n = 2048
+    keys = np.unique(rng.integers(0, 10**6, (2 * n, 2)).astype(np.int32), axis=0)[:n]
+    table = ops.build_table(jnp.asarray(keys))
+    slots = np.asarray(table.slots)
+    slot_of = np.empty(n, np.int64)
+    slot_of[slots[slots >= 0]] = np.flatnonzero(slots >= 0)
+    disp = slot_of - _home(keys, ops._next_pow2(n))
+    assert disp.min() == 0 and disp.max() == int(table.max_disp)
+    if bound is None:
+        want = np.arange(n)
+    else:
+        assert (disp > bound).any() and (disp <= bound).any()
+        table = table._replace(max_disp=jnp.int32(bound))
+        want = np.where(disp <= bound, np.arange(n), -1)
+    got = ops.probe(table, jnp.asarray(keys), impl="jnp")
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 @pytest.mark.parametrize("n", [1, 300, 5000])
 def test_build_table_host_sort_same_table(n, rng):
     """Both home-slot sorts are stable, so the radix kernel and lex_order
