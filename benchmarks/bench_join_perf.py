@@ -61,7 +61,12 @@ import jax
 from benchmarks.common import timeit
 from repro.core import ExecOptions, binary2fj, factor, free_join
 from repro.core.capacity import plan_capacities
-from repro.core.compiled import AdaptiveExecutor, make_count_fn, relations_to_cols
+from repro.core.compiled import (
+    AdaptiveExecutor,
+    count_value,
+    make_count_fn,
+    relations_to_cols,
+)
 from repro.core.plan import BinaryPlan
 from repro.relational.relation import Relation
 from repro.relational.schema import Atom, Query, triangle_query
@@ -112,7 +117,7 @@ def _run(q, rels, caps, budget, repeats=3):
     count, ovf = fn(cols)  # compile + 1st run
     assert not bool(ovf), "capacity overflow"
     t, _ = timeit(lambda: jax.block_until_ready(fn(cols)), repeats=repeats, warmup=1)
-    return t, int(count)
+    return t, count_value(count)
 
 
 def _run_adaptive(q, rels, repeats, compact_threshold):
@@ -120,7 +125,7 @@ def _run_adaptive(q, rels, repeats, compact_threshold):
     planned = plan_capacities(fj, rels, compact_threshold=compact_threshold)
     ex = AdaptiveExecutor(fj, planned, agg="count")
     cols = relations_to_cols(fj, rels)
-    count = int(ex(cols))  # compile (+ any overflow growth) + 1st run
+    count = count_value(ex(cols))  # compile (+ any overflow growth) + 1st run
     t, _ = timeit(lambda: jax.block_until_ready(ex(cols)), repeats=repeats, warmup=1)
     return t, count, ex, planned
 

@@ -88,7 +88,10 @@ distributed.spmd_count are both thin drivers over the same stack):
   returns (count, need_expand, need_compact); agg=None returns (bound
   columns, valid mask, mult, need_expand, need_compact). Node i overflowed
   iff the need exceeds its capacity, and the need is the exact capacity the
-  retry loop should jump to.
+  retry loop should jump to. A count is a small int32 vector of 16-bit
+  limbs (wide_count), exact up to 2**63 - 1 without x64, which the host
+  reads with count_value; a lane multiplicity that would pass int32 is
+  refused (MULT_SAT), and a result it reaches raises CountOverflowError.
 * AdaptiveExecutor drives the whole chain in an overflow-retry loop (grow
   exactly the offending node straight to its reported need; tighten=True
   also shrinks >2x-oversized buffers to measured needs once), caching one
@@ -121,6 +124,111 @@ from repro.kernels import ops, scan
 # max, so pad rows lose every probe immediately; correctness does not rest
 # on that (their multiplicity is 0), it only keeps dead lanes short-lived.
 PAD_KEY = np.int32(2**31 - 1)
+
+# Multiplicities ride the frontier as int32 (JAX runs without x64). A lane
+# whose product would reach 2**31 - 1 is refused instead of wrapped: it
+# reads MULT_SAT from then on, and a result that a refused lane reaches
+# raises CountOverflowError on the host. A count is summed wide (wide_count).
+MULT_SAT = np.int32(2**31 - 1)
+COUNT_LIMBS = 4  # 16-bit limbs of a wide count: exact up to 2**63 - 1
+_FOLD = 1 << 14  # values per partial sum: 2**14 limbs below 2**17 stay int32
+_SEG_BLOCK = 1 << 15  # rows per block of a saturating segment sum
+
+
+class CountOverflowError(OverflowError):
+    """A multiplicity or a count the int32 lanes cannot hold exactly: some
+    lane's product reached 2**31 - 1 and that lane reaches the result."""
+
+
+def mul_checked(m, x):
+    """m * x for nonnegative int32 lanes, saturating: a product of
+    2**31 - 1 or more reads MULT_SAT, and a MULT_SAT lane stays MULT_SAT
+    (for x > 0). A zero factor gives an exact zero. No division (a TPU
+    compiles an int32 divide slowly): the float32 product is within a
+    relative 2**-22 of the true one, so it decides every product below
+    2**30 or from 2**31.5 up; between, the true product is below 2**32, and
+    the int32 product has wrapped negative exactly when it reached 2**31."""
+    x = x.astype(jnp.int32)
+    p = m * x
+    pf = m.astype(jnp.float32) * x.astype(jnp.float32)
+    over = (pf >= 2.0**31.5) | ((pf >= 2.0**30) & ((p < 0) | (p == MULT_SAT)))
+    return jnp.where(over, MULT_SAT, p)
+
+
+def saturating_segment_sum(data, segment_ids, num_segments: int):
+    """segment_sum of nonnegative int32 `data` over non-decreasing
+    `segment_ids`, exact below MULT_SAT and MULT_SAT at or above it, where
+    an int32 sum would wrap. Rows are cut into blocks of _SEG_BLOCK, so each
+    (segment, block) pair sums its 16-bit halves without wrapping; the pairs'
+    carried halves then sum per segment, the high half in float32 as well:
+    a float32 sum of integers reaches 2**15 exactly when the true sum does
+    (below 2**24 every partial sum is exact, in any order)."""
+    n = data.shape[0]
+    if n == 0:
+        return jnp.zeros(num_segments, jnp.int32)
+    npair = num_segments + (n - 1) // _SEG_BLOCK + 1
+    # (segment, block) is sorted along the rows, and so is its sum
+    pair = segment_ids + jnp.arange(n, dtype=jnp.int32) // _SEG_BLOCK
+    hi = _sorted_segment_sum(data >> 16, pair, npair)  # < 2**30
+    lo = _sorted_segment_sum(data & 0xFFFF, pair, npair)  # < 2**31
+    h, l = hi + (lo >> 16), lo & 0xFFFF
+    # each pair's segment; pair ids no row took weigh 0 and join the last
+    # segment before them, which keeps the ids sorted
+    owner = jax.ops.segment_max(segment_ids, pair, npair, indices_are_sorted=True)
+    owner = jnp.maximum(scan.cummax(owner), 0)
+    big = _sorted_segment_sum(h.astype(jnp.float32), owner, num_segments) >= 2.0**15
+    h = _sorted_segment_sum(h, owner, num_segments)  # exact unless `big`
+    l = _sorted_segment_sum(l, owner, num_segments)  # < 2**31 while n < 2**30
+    big = big | (l > (MULT_SAT - 1) - (jnp.clip(h, 0, 2**15 - 1) << 16))
+    return jnp.where(big, MULT_SAT, (h << 16) + l)
+
+
+def _fold_limbs(limbs: list) -> list:
+    """One step of a wide sum: each limb's values (< 2**17) summed in
+    groups of _FOLD, then the carries moved one limb up."""
+    pad = (-limbs[0].shape[0]) % _FOLD
+    out, carry = [], 0
+    for limb in limbs:
+        s = jnp.pad(limb, (0, pad)).reshape(-1, _FOLD).sum(axis=1)
+        out.append((s & 0xFFFF) + carry)
+        carry = s >> 16
+    return out + [carry]
+
+
+def wide_count(mult, valid) -> jnp.ndarray:
+    """The exact sum of `mult` over the valid lanes: a (COUNT_LIMBS + 1,)
+    int32 vector, COUNT_LIMBS little-endian 16-bit limbs (each below 2**17,
+    so a psum over shards cannot wrap) and the number of refused lanes
+    (valid lanes at MULT_SAT), which the sum leaves out. count_value reads
+    it on the host."""
+    assert mult.shape[0] < 2**31, "lane count past int32"
+    m = jnp.where(valid, mult, 0)
+    if m.shape[0] == 0:
+        m = jnp.zeros(1, jnp.int32)
+    refused = jnp.sum(m == MULT_SAT, dtype=jnp.int32)
+    m = jnp.where(m == MULT_SAT, 0, m)
+    limbs = [m & 0xFFFF, m >> 16]
+    while limbs[0].shape[0] > 1:
+        limbs = _fold_limbs(limbs)
+    # total < 2**62, so limbs past COUNT_LIMBS hold zero
+    limbs = (limbs + [jnp.zeros(1, jnp.int32)] * COUNT_LIMBS)[:COUNT_LIMBS]
+    return jnp.concatenate([jnp.concatenate(limbs), refused[None]])
+
+
+def count_value(words):
+    """A wide count read back on the host (see wide_count) as a Python int,
+    or a (B,) int64 array for a batch of them; raises CountOverflowError
+    when a refused lane reached it (and counts those lanes in the counter
+    `executor.refused_lanes`)."""
+    w = np.asarray(words, np.int64)
+    refused = int(w[..., COUNT_LIMBS].sum())
+    if refused:
+        obs.count("executor.refused_lanes", refused)
+        raise CountOverflowError(
+            f"{refused} lanes' multiplicity reached 2**31 - 1; the count would wrap"
+        )
+    total = sum(w[..., k] << (16 * k) for k in range(COUNT_LIMBS))
+    return int(total) if w.ndim == 1 else np.asarray(total, np.int64)
 
 
 @dataclass(frozen=True)
@@ -190,7 +298,8 @@ class StaticTrie:
     counts (for last-level enumeration addressing) and mult sums (for
     factorized counting and bag multiplicity) — and the executor folds the
     per-row mult in (and kills mult-0 lanes) whenever it enumerates physical
-    rows."""
+    rows. Mult sums saturate at MULT_SAT (saturating_segment_sum), so a
+    group too heavy for int32 refuses the lanes that read it."""
 
     def __init__(
         self,
@@ -220,7 +329,10 @@ class StaticTrie:
         self.n = n
         self.cols = {k: v.astype(jnp.int32) for k, v in cols.items()}
         self.mult_col = None if mult is None else mult.astype(jnp.int32)
-        self.total_mult = None if mult is None else jnp.sum(self.mult_col)
+        self.total_mult = None
+        if mult is not None:
+            zeros = jnp.zeros(n, jnp.int32)
+            self.total_mult = saturating_segment_sum(self.mult_col, zeros, 1)[0]
         self.trivial = self.L == 1 and not lops.probed[0]
         self.order = None
         self.sorted_cols = None
@@ -268,7 +380,7 @@ class StaticTrie:
             self.child_counts.append(ccnt.astype(jnp.int32))
             self.row_count.append(rcnt)
             if sm is not None:
-                self.row_weight.append(_sorted_segment_sum(sm, gd1, n))
+                self.row_weight.append(saturating_segment_sum(sm, gd1, n))
             # probed levels get their hash table; one shared construction
             # with the lazy path (build_level_table), so eagerly- and
             # lazily-built tables can never drift
@@ -592,9 +704,10 @@ def _retire_rows_jit(mult, order, groups, rows):
     structure, and hash tables are untouched — dead rows keep their slots
     and simply weigh nothing."""
     mult = mult.at[rows].set(0, indices_are_sorted=True, unique_indices=True)  # np.unique'd
-    total = jnp.sum(mult)
+    n = mult.shape[0]
+    total = saturating_segment_sum(mult, jnp.zeros(n, jnp.int32), 1)[0]
     sm = mult[order] if order is not None else mult
-    weights = [_sorted_segment_sum(sm, gd1, mult.shape[0]) for gd1 in groups]
+    weights = [saturating_segment_sum(sm, gd1, n) for gd1 in groups]
     return mult, total, weights
 
 
@@ -927,8 +1040,10 @@ def make_executor(
     remaining probes run at the squeezed width); schedule: the query's
     StaticSchedule if the driver already computed it (None = walk the plan
     here). Returns fn(rel_data, rel_mults) ->
-      agg="count":  (count, need_expand, need_compact)
+      agg="count":  (count words, need_expand, need_compact)
       agg=None:     (bound, valid, mult, need_expand, need_compact)
+    The count words are wide_count's (read them with count_value); in an
+    agg=None output a refused lane carries mult MULT_SAT.
     rel_data maps alias -> either a prebuilt StaticTrie (the warm path:
     zero build work in this call) or {var: (N,) int32} raw columns (built
     in-graph — the cold path, and the only path for weighted stage
@@ -1012,7 +1127,9 @@ def make_executor(
         # frontier
         cap = 1
         valid = jnp.ones(1, dtype=bool)
-        mult = jnp.ones(1, jnp.int32)  # int64 needs x64; counts < 2^31 here
+        # int32 per lane, every product checked (mul_checked); the count
+        # itself is summed wide at the end (wide_count)
+        mult = jnp.ones(1, jnp.int32)
         bound: dict[str, jnp.ndarray] = {}
         gid: dict[str, jnp.ndarray] = {}
         # mask-mode filter state (filter_kill=False): per-lane liveness that
@@ -1055,7 +1172,7 @@ def make_executor(
                     # factorized count (static decision)
                     with jax.named_scope("count"):
                         rows = t.rows_under(d, g)
-                        mult = mult * jnp.where(valid, rows, 1).astype(jnp.int32)
+                        mult = mul_checked(mult, jnp.where(valid, rows, 1))
                     gid.pop(cover.alias, None)
                     depth[cover.alias] = t.L
                 else:
@@ -1098,7 +1215,7 @@ def make_executor(
                             # in here and whose mult-0 pad rows die on the spot.
                             rm = t.iter_mult(memc)
                             if rm is not None:
-                                mult = mult * jnp.where(valid, rm, 1)
+                                mult = mul_checked(mult, jnp.where(valid, rm, 1))
                                 valid = valid & (rm > 0)
                             gid.pop(cover.alias, None)
                         else:
@@ -1116,7 +1233,7 @@ def make_executor(
                         depth[sa.alias] = dp + 1
                         if depth[sa.alias] == tp.L:
                             rows = tp.rows_under(tp.L, childc)
-                            mult = mult * jnp.where(valid, rows, 1).astype(jnp.int32)
+                            mult = mul_checked(mult, jnp.where(valid, rows, 1))
                             gid.pop(sa.alias, None)
                         else:
                             gid[sa.alias] = childc
@@ -1143,7 +1260,7 @@ def make_executor(
             if fvalid[0] is not None:  # mask-mode filters fold in only here
                 valid = valid & fvalid[0]
             if agg == "count":
-                return jnp.sum(jnp.where(valid, mult, 0)), ne, nc
+                return wide_count(mult, valid), ne, nc
             # lanes that went through a weighted trie's probe path can survive
             # with mult 0 (pad groups weigh nothing); they are not output rows
             valid = valid & (mult > 0)
@@ -1250,7 +1367,8 @@ def make_count_fn(
     *,
     schedule: StaticSchedule | None = None,
 ):
-    """Original count-only surface: fn(rel_cols) -> (count, overflowed).
+    """Original count-only surface: fn(rel_cols) -> (count words,
+    overflowed); count_value reads the words.
     One scalar overflow flag; no compaction. Kept for benchmarks and dry
     runs — the SPMD driver (core/distributed.py) uses make_executor's need
     vectors directly so its retry loop can grow the offending node."""
@@ -1296,7 +1414,7 @@ def count_query(
     if jit:
         fn = jax.jit(fn)
     count, overflow = fn(rel_cols)
-    return int(count), bool(overflow)
+    return count_value(count), bool(overflow)
 
 
 def relations_to_cols(plan: FreeJoinPlan, relations) -> dict[str, dict[str, jnp.ndarray]]:
@@ -1506,11 +1624,12 @@ class AdaptiveExecutor:
         raise CapacityQuotaError(s, i, int(need), self.max_capacity, lane=lane)
 
     def __call__(self, rel_data: dict[str, object], filter_consts=None):
-        """agg="count" -> count scalar; agg=None -> (bound, valid, mult).
+        """agg="count" -> count words (count_value reads them); agg=None ->
+        (bound, valid, mult).
         rel_data values are prebuilt StaticTries and/or raw column dicts
         (see make_executor). filter_consts: (F,) int32 in filter_vars
-        order — or (batch, F) for a batched runner, which returns (B,)
-        counts (agg="count") or per-lane (bound, valid, mult). The call
+        order — or (batch, F) for a batched runner, which returns (B, W)
+        count words (agg="count") or per-lane (bound, valid, mult). The call
         is the span `fj.executor.call`, with a sequence id as its stat."""
         with obs.span("fj.executor.call", seq=obs.seq()):
             return self._call(rel_data, filter_consts)
@@ -1722,8 +1841,7 @@ class AdaptiveExecutor:
         if self.agg == "count":
             # explicit d2h: the count read-back is the warm path's only
             # result transfer (see the transfer-guard regression test)
-            host = obs.read(out, "fj.result.read")
-            return np.asarray(host, np.int64) if self.batch else int(host)
+            return count_value(obs.read(out, "fj.result.read"))
         if self.batch:
             bound, valid, mult = out
             return [
@@ -1738,8 +1856,13 @@ class AdaptiveExecutor:
 def materialize_compiled(bound, valid, mult):
     """Strip padding lanes from an agg=None result: returns (cols, mult) as
     host numpy arrays over live rows only (the eager engine's contract —
-    expand duplicate multiplicities with engine.materialize)."""
+    expand duplicate multiplicities with engine.materialize). Raises
+    CountOverflowError where a live row's multiplicity was refused."""
     bound, valid, mult = obs.read((bound, valid, mult), "fj.result.read")
     v = np.asarray(valid)
+    refused = int((np.asarray(mult)[v] == MULT_SAT).sum())
+    if refused:
+        obs.count("executor.refused_lanes", refused)
+        raise CountOverflowError(f"{refused} rows' multiplicity reached 2**31 - 1")
     cols = {name: np.asarray(a)[v].astype(np.int64) for name, a in bound.items()}
     return cols, np.asarray(mult)[v].astype(np.int64)

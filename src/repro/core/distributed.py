@@ -22,7 +22,8 @@ fragment sizes are the actual padded per-shard maxima and distinct counts
 shrink by the hypercube share of each variable — reusing the query's one
 Stats cache and one StaticSchedule. Inside the collective each device runs
 make_executor, which reports per-node *required totals*; the psum carries
-the count and a pmax carries the needs, and the overflow-retry loop runs on
+the count's 16-bit limbs (exact past 2**31, see compiled.wide_count) and a
+pmax carries the needs, and the overflow-retry loop runs on
 the host *outside* shard_map: grow exactly the offending node
 (CapacityPlan.grow_to), recompile at the new capacity vector, re-run. No
 overflow sentinel exists anywhere — spmd_count either returns the exact
@@ -47,6 +48,7 @@ from repro.core.capacity import CapacityPlan, plan_capacities
 from repro.core.compiled import (
     StaticTrie,
     _static_schedule,
+    count_value,
     make_executor,
     overflows,
 )
@@ -352,8 +354,9 @@ def spmd_count_program(plan, capacities, schedule, mesh, axis: str, impl: str, t
     def per_shard(tries):
         tries = jax.tree.map(lambda x: x[0], tries)
         c, ne, nc = local(tries)
-        # count by psum; needs by pmax — the host retry loop sizes every
-        # device's next capacities to the worst shard's need
+        # count by psum of its limbs (each below 2**17, so the sum over
+        # shards stays exact); needs by pmax — the host retry loop sizes
+        # every device's next capacities to the worst shard's need
         return jax.lax.psum(c, axis), jax.lax.pmax(ne, axis), jax.lax.pmax(nc, axis)
 
     return jax.jit(
@@ -495,9 +498,7 @@ class SpmdCounter:
                     if len(_cap_plan_cache) >= _CAP_PLAN_CACHE_MAX:
                         _cap_plan_cache.clear()
                     _cap_plan_cache[self._plan_key] = cp
-                total = int(total)
-                assert total >= 0, f"spmd count must be non-negative, got {total}"
-                return total
+                return count_value(total)
             ne, nc = np.asarray(ne), np.asarray(nc)
             # compaction is off under shard_map today, but grow symmetrically
             # with AdaptiveExecutor so the two retry loops cannot diverge

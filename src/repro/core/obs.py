@@ -21,7 +21,12 @@ with the next one. Counters today:
   plus its overflow and tightening re-runs;
 * `executor.lanes`: the frontier lanes the expansions of the answering
   dispatch produced (the sum of its need vector);
-* `sync_reads`: blocking device-to-host reads, all made by `read`.
+* `sync_reads`: blocking device-to-host reads, all made by `read`;
+* `executor.refused_lanes`: lanes (or output rows) whose multiplicity
+  reached 2**31 - 1 and reached a result, which was then refused
+  (`compiled.CountOverflowError`);
+* `standing.stage_rows`: the valid output rows of each recomputed
+  non-root stage of a standing query, read with the root's result.
 
 `snapshot()` returns the totals and counters. There is nothing to turn on
 or off: the trace holds the spans while a profiler runs, and only then.

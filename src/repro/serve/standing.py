@@ -43,6 +43,8 @@ from repro.core.compiled import (
     PAD_KEY,
     TRIE_CACHE,
     AdaptiveExecutor,
+    CountOverflowError,
+    count_value,
     device_columns,
     host_sorted_trie,
     materialize_compiled,
@@ -262,9 +264,15 @@ class StandingQueryEngine:
                 )
 
     def _refresh_stages(self, sq: StandingQuery, runners) -> bool:
+        """Recompute the stages whose fingerprints moved, each in the span
+        `fj.standing.stage` (stats `stage`, its index, and `root`). The
+        valid rows of each recomputed non-root stage's output come back with
+        the root's result, in the same read, as the counter
+        `standing.stage_rows`."""
         rels = sq.template.relations
         states_by_name = sq.states_by_name
         root_changed = False
+        stage_rows = []  # device scalars: valid rows of recomputed stages
         for i, (_name, plan, runner, _fv) in enumerate(runners):
             state = sq.states[i]
             stage_names = set(sq._stage_names[:i])
@@ -274,31 +282,50 @@ class StandingQueryEngine:
                 self.stages_skipped += 1
                 continue
             self.stages_recomputed += 1
-            try:
-                data = self._stage_data(plan, stage_names, rels, runner, states_by_name)
-                out = runner(data, sq.stage_consts[i])
-            except Exception as e:
-                # a standing query has no co-batched tenants to protect, so
-                # a runtime capacity quota degrades like any device fault:
-                # answer from the eager host engine, keep the result live
-                if not (faults.recoverable(e) or isinstance(e, CapacityQuotaError)):
-                    raise
-                self._recover_eager(sq)
-                return True
+            with obs.span("fj.standing.stage", stage=i, root=int(is_root)):
+                try:
+                    data = self._stage_data(plan, stage_names, rels, runner, states_by_name)
+                    out = runner(data, sq.stage_consts[i])
+                    if is_root:
+                        result = self._read_result(sq, out, stage_rows)
+                except Exception as e:
+                    # a standing query has no co-batched tenants to protect,
+                    # so a runtime capacity quota or a refused multiplicity
+                    # degrades like any device fault: answer from the eager
+                    # host engine, keep the result live and exact
+                    if not (
+                        faults.recoverable(e)
+                        or isinstance(e, (CapacityQuotaError, CountOverflowError))
+                    ):
+                        raise
+                    self._recover_eager(sq)
+                    return True
             if is_root:
-                if sq.template.agg == "count":
-                    sq.result = int(obs.read(out, "fj.result.read"))
-                else:
-                    sq.result = materialize_compiled(*out)
+                sq.result = result
                 sq.result_version += 1
                 sq.degraded_to = None
                 root_changed = True
             else:
                 state.out = out
                 state.tries = {}  # consumers rebuild from the fresh buffers
+                stage_rows.append(jnp.sum(out[1], dtype=jnp.int32))
             state.fingerprint = fp
             state.runs += 1
         return root_changed
+
+    @staticmethod
+    def _read_result(sq: StandingQuery, out, stage_rows: list):
+        """The root's result on the host, and the counter
+        `standing.stage_rows` read alongside it."""
+        if sq.template.agg == "count":
+            words, rows = obs.read((out, stage_rows), "fj.result.read")
+            result = count_value(words)
+        else:
+            result = materialize_compiled(*out)
+            rows = obs.read(stage_rows, "fj.result.read") if stage_rows else []
+        if rows:
+            obs.count("standing.stage_rows", int(sum(int(r) for r in rows)))
+        return result
 
     def _recover_eager(self, sq: StandingQuery) -> None:
         """Fault recovery: answer the query on the eager host engine over
@@ -337,24 +364,30 @@ class StandingQueryEngine:
         return tuple(parts)
 
     def _stage_data(self, plan, stage_names, rels, runner, states_by_name):
-        """Assemble the stage's rel_data dict: base aliases from the
-        delta-aware trie cache (or live-row columns when the schedule reads
-        raw), upstream stage aliases as weighted tries built once per
-        upstream run from the cached output buffers."""
+        """Assemble the stage's rel_data dict: upstream stage aliases as
+        weighted tries built once per upstream run from the cached output
+        buffers (each build in the span `fj.standing.stage_trie`), then base
+        aliases from the delta-aware trie cache (or live-row columns when
+        the schedule reads raw). Stage tries go first, so their device work
+        is queued before the base tries' merges."""
         data = {}
+        aliases = {sa.alias for node in plan.nodes for sa in node}
         with obs.span("fj.standing.stage_data"):
-            for a in {sa.alias for node in plan.nodes for sa in node}:
+            for a in sorted(aliases, key=lambda a: (a not in stage_names, a)):
                 if a in stage_names:
                     up = states_by_name[a]
                     lo = runner.schedule.level_ops[a]
                     key = (lo.levels, lo.probed)
                     trie = up.tries.get(key)
                     if trie is None:
-                        bound, valid, mult = up.out
-                        flat = [v for lv in lo.levels for v in lv]
-                        cols = {v: jnp.where(valid, bound[v], PAD_KEY) for v in flat}
-                        w = jnp.where(valid, mult, 0).astype(jnp.int32)
-                        trie = host_sorted_trie(cols, lo, runner.impl, runner.budget, mult=w)
+                        with obs.span("fj.standing.stage_trie"):
+                            bound, valid, mult = up.out
+                            flat = [v for lv in lo.levels for v in lv]
+                            cols = {v: jnp.where(valid, bound[v], PAD_KEY) for v in flat}
+                            w = jnp.where(valid, mult, 0).astype(jnp.int32)
+                            trie = host_sorted_trie(
+                                cols, lo, runner.impl, runner.budget, mult=w
+                            )
                         up.tries[key] = trie
                     data[a] = trie
                     continue

@@ -7,7 +7,12 @@ import jax.numpy as jnp
 
 from repro.core import compiled_free_join, free_join, optimize, to_sorted_tuples
 from repro.core.capacity import CapacityPlan, agm_bound, plan_capacities
-from repro.core.compiled import AdaptiveExecutor, make_executor, relations_to_cols
+from repro.core.compiled import (
+    AdaptiveExecutor,
+    count_value,
+    make_executor,
+    relations_to_cols,
+)
 from repro.core.optimizer import estimate_prefixes
 from repro.core.plan import binary2fj, factor
 from repro.kernels import ops, ref
@@ -192,7 +197,7 @@ def test_executor_with_forced_compaction_matches(rng):
     cols = relations_to_cols(fj, rels)
     plain = jax.jit(make_executor(fj, caps))(cols)
     squeezed = jax.jit(make_executor(fj, caps, compact_to=[1024, None]))(cols)
-    assert int(plain[0]) == want == int(squeezed[0])
+    assert count_value(plain[0]) == want == count_value(squeezed[0])
     # executors report *required totals* per node, not overflow bits
     assert (np.asarray(squeezed[1]) <= np.array(caps)).all()
     assert np.asarray(squeezed[2])[0] <= 1024
@@ -219,7 +224,7 @@ def test_midnode_compaction_between_probes(rng):
     for cpr in [None, cp.compact_probe]:  # after-node vs mid-node
         out = jax.jit(make_executor(fj, cp.capacities, compact_to=cp.compact_to,
                                     compact_probe=cpr))(cols)
-        assert int(out[0]) == want
+        assert count_value(out[0]) == want
         assert (np.asarray(out[1]) <= np.array(cp.capacities)).all()
         assert np.asarray(out[2])[0] <= cp.compact_to[0]
     ex = AdaptiveExecutor(fj, cp, agg="count")
